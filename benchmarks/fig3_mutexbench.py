@@ -11,8 +11,10 @@ Also runs the appendix variants (tkt-dual, twa-id, twa-staged, partitioned),
 the queue-lock baselines (anderson, clh, hemlock — Fissile Locks), the
 waiting-array counting semaphore (twa-sem, permits=4), and the PR-5
 compositions (fissile-twa fusion, twa-rw reader-writer at the default 50%
-read mix).  The whole figure — every registered lock × thread count × seed
-— is ONE SweepSpec and one compiled engine call.
+read mix).  ``twa-timo`` stays out: its abandonment ring has
+``TIMO_RING`` (32) slots, too few for 64 threads; fig12deg covers it.  The
+whole figure — 13 locks × 7 thread counts × 3 seeds — is ONE SweepSpec and
+one compiled engine call.
 """
 
 from __future__ import annotations
@@ -20,19 +22,24 @@ from __future__ import annotations
 import numpy as np
 
 from repro.sim import SIM_LOCKS
-from repro.sim.workloads import SweepSpec, run_sweep
+from repro.sim.workloads import DEFAULT_HORIZON, SweepSpec, run_sweep
 
 from .common import emit
 
 THREADS = (1, 2, 4, 8, 16, 32, 64)
-LOCKS = tuple(SIM_LOCKS)
+LOCKS = tuple(lk for lk in SIM_LOCKS if lk != "twa-timo")
+
+
+def spec(locks=LOCKS, threads=THREADS, runs: int = 3,
+         horizon: int = DEFAULT_HORIZON) -> SweepSpec:
+    """The figure's sweep: every lock × thread count × seed."""
+    return SweepSpec(locks=tuple(locks), threads=tuple(threads),
+                     seeds=tuple(range(1, runs + 1)), cs_work=4, ncs_max=200,
+                     collect_latency=True, horizon=horizon)
 
 
 def run(locks=LOCKS, threads=THREADS, runs: int = 3) -> dict:
-    spec = SweepSpec(locks=tuple(locks), threads=tuple(threads),
-                     seeds=tuple(range(1, runs + 1)), cs_work=4, ncs_max=200,
-                     collect_latency=True)
-    results = run_sweep(spec)
+    results = run_sweep(spec(locks, threads, runs))
     by_cell = {}
     for r in results:
         by_cell.setdefault((r["lock"], r["n_threads"]), []).append(r)

@@ -51,6 +51,8 @@ def main() -> None:
     ap.add_argument("--results", default="",
                     help="persist every sweep into this JSONL results store")
     args = ap.parse_args()
+    from repro.launch.compile_cache import use_checkout_cache
+    use_checkout_cache()
     only = [s for s in args.only.split(",") if s]
     if args.results:
         from repro.sim.workloads import RESULTS_STORE_ENV

@@ -9,29 +9,25 @@ select kernel vs pure-jnp oracle via use_pallas).
 All kernels (and the lockVM's ``mode="pallas"`` sweep driver in
 ``repro.sim.engine_pallas``) share :func:`default_interpret` to decide
 whether ``pallas_call`` should compile natively or run the interpreter:
-interpret exactly when no accelerator backend is present.  Every entry
-point keeps ``interpret`` overridable (and jit-static), so tests can force
-the interpreter on a device and device runs can be forced from CPU-hosted
-tracing.
+natively exactly on a TPU, the one accelerator this repo targets.  Every
+entry point keeps ``interpret`` overridable (and jit-static), so tests can
+force the interpreter on a device and device runs can be forced from
+CPU-hosted tracing.
 """
 
 from __future__ import annotations
 
 import jax
 
-# Backends whose Pallas lowering is real hardware; anything else (cpu, the
-# METAL/interpreter stand-ins) must run pallas_call in interpret mode.
-ACCELERATOR_BACKENDS = ("tpu", "gpu", "cuda", "rocm")
-
 
 def default_interpret() -> bool:
-    """True when ``pallas_call`` must interpret (no TPU/GPU backend).
+    """True when ``pallas_call`` must interpret (the backend is no TPU).
 
     Resolved at trace time: callers take ``interpret: bool | None = None``
     as a jit-static argument and substitute this when it is None, so the
     chosen value is baked into the compiled executable per backend.
     """
-    return jax.default_backend() not in ACCELERATOR_BACKENDS
+    return jax.default_backend() != "tpu"
 
 
 def resolve_interpret(interpret: bool | None) -> bool:

@@ -43,16 +43,16 @@ def _scan_kernel(x_ref, dt_ref, A_ref, B_ref, C_ref, Dsk_ref, h0_ref,
     Dsk = Dsk_ref[...].astype(jnp.float32)  # (1, D_TILE)
 
     def step(t, h):
-        row = (pl.dslice(t, 1), slice(None))
-        x_t = pl.load(x_ref, row)[0].astype(jnp.float32)    # (D_TILE,)
-        dt_t = pl.load(dt_ref, row)[0].astype(jnp.float32)
-        B_t = pl.load(B_ref, row)[0].astype(jnp.float32)    # (N,)
-        C_t = pl.load(C_ref, row)[0].astype(jnp.float32)
+        row = (pl.ds(t, 1), slice(None))
+        x_t = x_ref[row][0].astype(jnp.float32)     # (D_TILE,)
+        dt_t = dt_ref[row][0].astype(jnp.float32)
+        B_t = B_ref[row][0].astype(jnp.float32)     # (N,)
+        C_t = C_ref[row][0].astype(jnp.float32)
         dA = jnp.exp(dt_t[:, None] * A)
         dBx = (dt_t * x_t)[:, None] * B_t[None, :]
         h = dA * h + dBx
         y_t = (h * C_t[None, :]).sum(axis=1) + Dsk[0, :] * x_t
-        pl.store(y_ref, row, y_t[None, :].astype(y_ref.dtype))
+        y_ref[row] = y_t[None, :].astype(y_ref.dtype)
         return h
 
     h = jax.lax.fori_loop(0, l_chunk, step, h_ref[...])
@@ -69,7 +69,7 @@ def selective_scan_pallas(x, dt, A, B, C, D_skip, h0=None, *,
                           interpret: bool | None = None):
     """Pallas selective scan; same contract as ref.selective_scan_ref.
 
-    ``interpret=None`` autodetects: interpret on CPU, native on TPU/GPU.
+    ``interpret=None`` autodetects: native on a TPU, interpret elsewhere.
     """
     interpret = resolve_interpret(interpret)
     L, Dm = x.shape

@@ -76,7 +76,7 @@ def rglru_scan_pallas(a, b, h0=None, *, d_tile: int = LANE,
                       l_chunk: int = 256, interpret: bool | None = None):
     """Pallas RG-LRU scan; same contract as ref.rglru_scan_ref.
 
-    ``interpret=None`` autodetects: interpret on CPU, native on TPU/GPU.
+    ``interpret=None`` autodetects: native on a TPU, interpret elsewhere.
     """
     interpret = resolve_interpret(interpret)
     L, D = a.shape

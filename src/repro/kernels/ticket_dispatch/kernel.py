@@ -55,7 +55,7 @@ def ticket_dispatch_pallas(expert_ids: jnp.ndarray, n_experts: int,
                            interpret: bool | None = None) -> jnp.ndarray:
     """FIFO tickets for a flat int32 arrival sequence (any shape, flattened).
 
-    ``interpret=None`` autodetects: interpret on CPU, native on TPU/GPU
+    ``interpret=None`` autodetects: native on a TPU, interpret elsewhere
     (:func:`repro.kernels.default_interpret`); an explicit bool wins.
     """
     interpret = resolve_interpret(interpret)

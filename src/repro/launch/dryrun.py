@@ -178,7 +178,6 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
     ctx2 = act_seq_axes(seq_ax) if seq_ax else contextlib.nullcontext()
 
     t0 = time.time()
-    # use_mesh() shims the jax>=0.5-only set_mesh API down to 0.4.x
     with use_mesh(mesh), ctx, ctx2:
         if cell.kind == "train":
             A = accum if accum is not None else ACCUM.get(arch, 1)

@@ -15,6 +15,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import use_checkout_cache
 from repro.models.model import init_params
 from repro.serve import ServeEngine
 
@@ -30,6 +31,7 @@ def main(argv=None) -> dict:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_checkout_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -58,7 +60,7 @@ def main(argv=None) -> dict:
     for r in reqs[:4]:
         print(f"  req#{r.ticket}: prompt[:4]={r.prompt[:4]} "
               f"-> out={r.tokens_out}")
-    return {"requests": reqs, "stats": stats}
+    return {"requests": reqs, "stats": stats, "engine": eng}
 
 
 if __name__ == "__main__":

@@ -56,50 +56,19 @@ def act_seq_axes(axes):
 
 
 def use_mesh(mesh):
-    """Context manager making ``mesh`` ambient, across the supported jax range.
+    """Context manager making ``mesh`` ambient (``jax.set_mesh``).
 
-    Preference order: ``jax.sharding.use_mesh`` (the documented context
-    manager on the 0.5/0.6 line), then ``jax.set_mesh`` (its successor —
-    context-manager form from 0.6).  On 0.4.x neither exists, so fall back
-    to entering the ``Mesh`` itself (the thread-local physical mesh), which
-    :func:`ambient_mesh` — and therefore :func:`constrain` and jit
-    in_shardings — resolves identically.  Mirror of the ``ambient_mesh()``
-    read-side shim: every mesh *write* must route through here, never
-    ``jax.set_mesh`` directly.
+    Every mesh write routes through here and every read through
+    :func:`ambient_mesh`, so :func:`constrain` and jit in_shardings see the
+    same mesh.
     """
-    setter = getattr(jax.sharding, "use_mesh", None) \
-        or getattr(jax, "set_mesh", None)
-    if setter is not None:
-        return setter(mesh)
-    return mesh  # jax 0.4.x: Mesh is its own context manager
+    return jax.set_mesh(mesh)
 
 
 def ambient_mesh():
-    """The ambient mesh (abstract or physical), or None when unset.
-
-    ``jax.sharding.get_abstract_mesh`` only exists from jax 0.5; on the 0.4.x
-    line the internal accessor exists but returns a bare ``()`` sentinel when
-    no abstract mesh is active.  Accept either, then fall back to the
-    thread-local physical mesh (``with mesh:``) so both mesh-entry styles
-    work across the supported jax range (>= 0.4.30).
-    """
-    try:
-        from jax._src import mesh as _mesh_internal
-    except ImportError:
-        _mesh_internal = None
-    get = getattr(jax.sharding, "get_abstract_mesh", None) \
-        or getattr(_mesh_internal, "get_abstract_mesh", None)
-    mesh = get() if get is not None else None
-    if getattr(mesh, "empty", True):  # None, the () sentinel, or truly empty
-        mesh = None
-    if mesh is None:
-        try:
-            physical = _mesh_internal.thread_resources.env.physical_mesh
-            if physical is not None and not physical.empty:
-                mesh = physical
-        except AttributeError:
-            pass
-    return mesh
+    """The ambient mesh, or None when unset."""
+    mesh = jax.sharding.get_abstract_mesh()
+    return None if mesh.empty else mesh
 
 
 def mesh_axis_size(name: str) -> int:

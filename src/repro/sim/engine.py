@@ -56,12 +56,13 @@ Structure (batched-sweep refactor):
     changes).  ``mode="pallas"`` (:mod:`repro.sim.engine_pallas`) fuses the
     whole per-cell event loop into one Pallas kernel grid step — hot state
     resident in kernel memory across a ``chunk``-event burst instead of a
-    per-event ``lax.while_loop`` carry; interpret mode on CPU, native on
-    TPU/GPU.  ``mode="auto"`` (:func:`choose_mode`) picks a driver from the
-    backend kind plus the sweep shape.  Cells with fewer threads than the
-    batch maximum mask the excess threads inactive (``next_time = INF``
-    forever), which leaves their per-event behaviour bit-identical to an
-    unpadded run.
+    per-event ``lax.while_loop`` carry; it runs in interpret mode on CPU and
+    does not lower for TPU yet (see :data:`repro.sim.engine_pallas.
+    TPU_REFUSAL`).  ``mode="auto"`` (:func:`choose_mode`) picks a driver
+    from the backend kind plus the sweep shape.  Cells with fewer threads
+    than the batch maximum mask the excess threads inactive (``next_time =
+    INF`` forever), which leaves their per-event behaviour bit-identical to
+    an unpadded run.
 """
 
 from __future__ import annotations
@@ -1052,35 +1053,30 @@ DEFAULT_CHUNK = 512
 
 # mode="auto" thresholds: a sweep is "skewed" when its heaviest cell carries
 # at least twice the mean estimated work and there are enough cells for the
-# work-stealing scheduler to amortize its refill machinery; the pallas fast
-# path requires a cell's resident hot state to fit the kernel-memory budget
-# (VMEM is ~16 MB/core on TPU — half of it, leaving room for double-buffered
-# input blocks).
+# work-stealing scheduler to amortize its refill machinery.
 AUTO_SKEW_RATIO = 2.0
 AUTO_SKEW_MIN_CELLS = 8
-PALLAS_STATE_BUDGET = 8 << 20
 
 
 def choose_mode(backend: str, *, n_cells: int, n_threads: int,
-                mem_words: int, horizon, n_active=None) -> str:
+                horizon, n_active=None) -> str:
     """Pick a sweep driver from the backend kind and the sweep shape.
 
-    The decision surface (all four modes are bit-identical, so this is
-    purely a performance policy):
+    The decision surface (all drivers are bit-identical, so this is purely
+    a performance policy):
 
-    * **cpu** — the scalar step sees no SIMD benefit, so sequential
-      ``"map"`` pays exactly ``sum(events)``; a *skewed* sweep (one cell's
-      estimated work ≥ ``AUTO_SKEW_RATIO`` × the mean, with at least
-      ``AUTO_SKEW_MIN_CELLS`` cells) goes to the work-stealing ``"sched"``
-      driver, which keeps lanes busy across the skew.
-    * **tpu/gpu** — the fused-kernel ``"pallas"`` driver removes the
-      per-event dispatch that dominates the XLA loop drivers, provided the
-      per-cell hot state fits the kernel-memory budget
-      (:data:`PALLAS_STATE_BUDGET`); oversized cells fall back to
-      lane-parallel ``"vmap"`` (uniform sweeps) or ``"sched"`` (skewed).
+    * a *skewed* sweep (one cell's estimated work ≥ ``AUTO_SKEW_RATIO`` ×
+      the mean, with at least ``AUTO_SKEW_MIN_CELLS`` cells) goes to the
+      work-stealing ``"sched"`` driver, which keeps lanes busy across the
+      skew, on every backend;
+    * otherwise **tpu** runs the cells lane-parallel (``"vmap"``), and the
+      host backends run them one after another (``"map"``): a scalar step
+      sees no SIMD benefit there, so ``"map"`` pays exactly ``sum(events)``.
 
-    Work per cell is estimated as ``horizon × n_active`` — the event count
-    is horizon-bound for live cells and padded threads never run.
+    ``"pallas"`` is never picked: Mosaic refuses its kernel
+    (:data:`repro.sim.engine_pallas.TPU_REFUSAL`).  Work per cell is
+    estimated as ``horizon × n_active`` — the event count is horizon-bound
+    for live cells and padded threads never run.
     """
     horizon = np.broadcast_to(np.asarray(horizon, np.int64), (n_cells,))
     if n_active is None:
@@ -1089,12 +1085,9 @@ def choose_mode(backend: str, *, n_cells: int, n_threads: int,
     est = horizon * n_active
     skewed = (n_cells >= AUTO_SKEW_MIN_CELLS
               and est.max() * n_cells >= AUTO_SKEW_RATIO * est.sum())
-    if backend == "cpu":
-        return "sched" if skewed else "map"
-    from .engine_pallas import cell_state_bytes
-    if cell_state_bytes(n_threads, mem_words) > PALLAS_STATE_BUDGET:
-        return "sched" if skewed else "vmap"
-    return "pallas"
+    if skewed:
+        return "sched"
+    return "vmap" if backend == "tpu" else "map"
 
 
 def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
@@ -1131,14 +1124,15 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         program, "sched" runs a work-stealing lane scheduler (pays
         ~sum(events) like "map" but keeps ``lanes`` cells in flight — the
         right choice for skewed sweeps), "pallas" fuses each cell's whole
-        event loop into one Pallas-kernel grid step (interpret mode on CPU,
-        native on TPU/GPU), "auto" picks by backend kind + sweep shape
+        event loop into one Pallas-kernel grid step (interpret mode only:
+        natively it raises ``NotImplementedError``, as Mosaic refuses the
+        kernel), "auto" picks by backend kind + sweep shape
         (:func:`choose_mode`).  Results are bit-identical across all modes.
       lanes/chunk: driver geometry — ``lanes`` ("sched" only) is the number
         of parallel work-stealing lanes (clamped to B); ``chunk`` ("sched"
         and "pallas") is the steps per burst between termination checks.
       interpret: "pallas" only — force the Pallas interpreter on/off; None
-        autodetects (interpret unless a TPU/GPU backend is present).
+        autodetects (interpret unless the backend is a TPU).
       live_mem_words: optional (B,) per-cell *unpadded* memory sizes, used
         only for the ``pad_stats`` waste report (defaults to ``mem_words``,
         i.e. no padding assumed).
@@ -1167,8 +1161,7 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
     if mode == "auto":
         backend = jax.default_backend()
         mode = choose_mode(backend, n_cells=n_cells, n_threads=n_threads,
-                           mem_words=mem_words, horizon=horizon,
-                           n_active=n_active)
+                           horizon=horizon, n_active=n_active)
         log.info("run_sweep mode='auto' -> %r (backend=%s, B=%d, "
                  "n_threads=%d, mem_words=%d)", mode, backend, n_cells,
                  n_threads, mem_words)
@@ -1179,12 +1172,14 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         assert lanes >= 1 and chunk >= 1, (lanes, chunk)
     elif mode == "pallas":
         from ..kernels import resolve_interpret
-        from .engine_pallas import DEFAULT_PALLAS_CHUNK
+        from .engine_pallas import DEFAULT_PALLAS_CHUNK, TPU_REFUSAL
         assert lanes is None, "lanes only applies to mode='sched'"
         lanes = 0
         chunk = DEFAULT_PALLAS_CHUNK if chunk is None else chunk
         assert chunk >= 1, chunk
         interpret = resolve_interpret(interpret)
+        if not interpret:
+            raise NotImplementedError(TPU_REFUSAL)
     else:
         assert lanes is None and chunk is None, \
             f"lanes/chunk only apply to mode='sched'/'pallas', " \

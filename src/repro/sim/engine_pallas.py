@@ -29,13 +29,9 @@ driver against the NumPy oracle alongside the other modes.
 
 Backend story: with ``interpret=True`` (the CPU default via
 :func:`repro.kernels.default_interpret`) the kernel is discharged to
-ordinary XLA and serves as the correctness reference; on a TPU/GPU backend
-``interpret=False`` lowers natively.  Cells execute one grid step each —
-sequential on TPU grids (so a skewed sweep costs ~``sum(events)`` like
-``mode="map"``, *without* per-event dispatch), parallel blocks on GPU.
-Per-cell hot state must fit kernel memory (~16 MB VMEM on TPU);
-:func:`cell_state_bytes` is the estimate ``mode="auto"`` uses to fall back
-to vmap/sched for oversized cells.
+ordinary XLA and serves as the correctness reference.  It does not lower
+for TPU: :func:`repro.sim.engine.run_sweep` raises :data:`TPU_REFUSAL`
+instead of dropping to the interpreter, and ``mode="auto"`` never picks it.
 """
 
 from __future__ import annotations
@@ -45,8 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import isa
-from .engine import (INF, N_LAT_BUCKETS, SimConsts, _initial_state, _step,
-                     bitset_words)
+from .engine import INF, N_LAT_BUCKETS, SimConsts, _initial_state, _step
 
 # Events per in-kernel burst between termination checks.  The burst loop
 # costs ``ceil(events / chunk) * chunk`` steps per cell (overshoot steps are
@@ -54,26 +49,19 @@ from .engine import (INF, N_LAT_BUCKETS, SimConsts, _initial_state, _step,
 # cell while the termination reduction is amortized over ``chunk`` events.
 DEFAULT_PALLAS_CHUNK = 128
 
+# Why run_sweep refuses mode="pallas" natively: what Mosaic, the TPU Pallas
+# compiler, says of this kernel when compiled for a v5e.
+TPU_REFUSAL = (
+    "mode='pallas' does not lower for TPU. Mosaic refuses the sweep "
+    "kernel's (1, n_threads) and (1,) blocks: the last two block dimensions "
+    "must divide by 8 and 128 or span the array. With those worked around, "
+    "it refuses the int32 argmin in engine._step: 'Only float32 is "
+    "supported'. Use mode='auto', 'map', 'vmap' or 'sched'.")
+
 # Result keys, in kernel-output order (the engine's sweep-output contract).
 OUT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
             "handover_count", "events", "sleeping", "grant_value",
             "lat_hist")
-
-
-def cell_state_bytes(n_threads: int, mem_words: int) -> int:
-    """Bytes of per-cell hot state the kernel keeps resident during a burst.
-
-    Everything in :class:`SimState`: memory, packed sharer bitsets + dirty
-    owners per line, and the eight per-thread int32 rows plus the register
-    file.  ``mode="auto"`` compares this against the kernel-memory budget
-    before picking the pallas driver.
-    """
-    n_lines = mem_words // isa.WORDS_PER_SECTOR
-    words = (mem_words
-             + n_lines * (bitset_words(n_threads) + 1)
-             + n_threads * (9 + isa.N_REGS)
-             + N_LAT_BUCKETS)
-    return 4 * words
 
 
 def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,

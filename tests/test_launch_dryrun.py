@@ -1,11 +1,9 @@
-"""Smoke coverage for the dry-run launcher's jax-version compatibility.
+"""Smoke coverage for the mesh helpers and the dry-run launcher's use of them.
 
-The 0.4.x drift history: every `jax.sharding`/mesh API the repo touches must
-go through a `shard_utils` shim (`ambient_mesh()` for reads, `use_mesh()`
-for writes).  `launch/dryrun.py` was the last module calling a jax>=0.5-only
-API (`jax.set_mesh`) directly — untested, so it regressed silently.  These
-tests pin both the shim's behaviour on the installed jax and dryrun's use of
-it.
+Every mesh the repo makes ambient goes through `shard_utils`
+(`ambient_mesh()` for reads, `use_mesh()` for writes), so `constrain` and
+jit in_shardings see the same mesh.  These tests pin the helpers'
+behaviour on the installed jax and dryrun's use of them.
 """
 
 import inspect
@@ -24,8 +22,8 @@ def _one_device_mesh():
 
 
 def test_use_mesh_makes_mesh_ambient():
-    """use_mesh must work on the installed jax (0.4.30+ has no
-    jax.set_mesh) and leave no ambient mesh behind on exit."""
+    """use_mesh must make the mesh ambient and leave no ambient mesh
+    behind on exit."""
     mesh = _one_device_mesh()
     assert ambient_mesh() is None
     with use_mesh(mesh):
@@ -46,8 +44,8 @@ def test_use_mesh_composes_with_jit():
 
 
 def test_dryrun_imports_and_routes_mesh_through_shim():
-    """Importing dryrun must succeed on any supported jax, and its mesh
-    entry must be the shard_utils shim, not jax.set_mesh."""
+    """Importing dryrun must succeed, and its mesh entry must be the
+    shard_utils helper, not jax.set_mesh."""
     saved = os.environ.get("XLA_FLAGS")
     try:
         from repro.launch import dryrun

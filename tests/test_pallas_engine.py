@@ -1,8 +1,9 @@
 """Pallas fast-path validation: ``mode="pallas"`` must be bit-identical to
 ``mode="map"`` on skewed sweeps for every burst chunk (overshoot steps are
-identity no-events), ``mode="auto"`` must pick different drivers at
-different (backend, sweep-shape) points, and every sweep must stamp its
-resolved mode and padding-waste report into the result."""
+identity no-events), must refuse to lower natively rather than drop to the
+interpreter, ``mode="auto"`` must pick different drivers at different
+(backend, sweep-shape) points and never ``"pallas"``, and every sweep must
+stamp its resolved mode and padding-waste report into the result."""
 
 import logging
 
@@ -13,8 +14,7 @@ import pytest
 from repro.sim import SweepSpec, choose_mode
 from repro.sim import engine
 from repro.sim.engine import engine_cache_info
-from repro.sim.engine_pallas import (DEFAULT_PALLAS_CHUNK, OUT_KEYS,
-                                     cell_state_bytes)
+from repro.sim.engine_pallas import OUT_KEYS
 from repro.sim.workloads import pack_engine_cells, run_sweep
 
 ON_CPU = jax.default_backend() == "cpu"
@@ -105,24 +105,44 @@ def test_pallas_single_compile_and_chunk_keyed_cache():
 
 def test_choose_mode_selects_distinct_drivers():
     """The auto policy must pick different drivers at distinct
-    (backend, sweep-shape) points — the whole point of mode="auto"."""
-    uniform = dict(n_cells=4, n_threads=8, mem_words=4608, horizon=10_000)
+    (backend, sweep-shape) points — the whole point of mode="auto" — and
+    never the pallas driver, which Mosaic refuses on a TPU."""
+    uniform = dict(n_cells=4, n_threads=8, horizon=10_000)
     skew_h = np.asarray([600_000] + [10_000] * 11)
-    skewed = dict(n_cells=12, n_threads=8, mem_words=4608, horizon=skew_h)
-    big = dict(n_cells=4, n_threads=64, mem_words=4_000_000, horizon=10_000)
-    assert cell_state_bytes(8, 4608) <= engine.PALLAS_STATE_BUDGET
-    assert cell_state_bytes(64, 4_000_000) > engine.PALLAS_STATE_BUDGET
+    skewed = dict(n_cells=12, n_threads=8, horizon=skew_h)
     assert choose_mode("cpu", **uniform) == "map"
     assert choose_mode("cpu", **skewed) == "sched"
-    assert choose_mode("tpu", **uniform) == "pallas"
-    assert choose_mode("gpu", **uniform) == "pallas"
-    assert choose_mode("tpu", **big) == "vmap"
-    assert choose_mode("tpu", n_cells=12, n_threads=64,
-                       mem_words=4_000_000, horizon=skew_h) == "sched"
+    assert choose_mode("tpu", **uniform) == "vmap"
+    assert choose_mode("tpu", **skewed) == "sched"
+    # the fig3 grid: one horizon, thread counts 1..64 -> skewed by n_active
+    fig3_active = np.repeat([1, 2, 4, 8, 16, 32, 64], 39)
+    assert choose_mode("tpu", n_cells=273, n_threads=64, horizon=1_500_000,
+                       n_active=fig3_active) == "sched"
     # the skew gate needs enough cells for stealing to pay off
-    few = dict(n_cells=2, n_threads=8, mem_words=4608,
+    few = dict(n_cells=2, n_threads=8,
                horizon=np.asarray([600_000, 10_000]))
     assert choose_mode("cpu", **few) == "map"
+    assert choose_mode("tpu", **few) == "vmap"
+
+
+def test_pallas_refuses_native_lowering():
+    """A native pallas sweep must fail loudly, naming Mosaic's refusal —
+    never fall back to the interpreter behind the caller's back."""
+    programs, kw = _skewed_sweep_args()
+    with pytest.raises(NotImplementedError, match="Mosaic"):
+        engine.run_sweep(programs, mode="pallas", interpret=False, **kw)
+
+
+def test_auto_mode_on_tpu_backend_picks_xla_driver(monkeypatch):
+    """Where the backend reports a TPU, auto resolves to an XLA driver."""
+    monkeypatch.setattr(engine.jax, "default_backend", lambda: "tpu")
+    cells = [("ticket", 2, 10_000), ("twa", 2, 10_000)]
+    programs, kw = pack_engine_cells(cells, ncs_max=100, seeds=3)
+    out = engine.run_sweep(programs, mode="auto", **kw)
+    assert out["mode"] == "vmap"
+    monkeypatch.undo()
+    _assert_same(engine.run_sweep(programs, mode="map", **kw), out,
+                 "auto-tpu")
 
 
 @pytest.mark.skipif(not ON_CPU, reason="asserts the CPU auto policy")
