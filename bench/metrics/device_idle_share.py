@@ -1,0 +1,9 @@
+"""device_idle_share: the share of the traced window in which no operation
+ran on the device, in percent (profiler trace)."""
+
+
+def read(run: dict) -> float | None:
+    trace = run.get("trace")
+    if not trace or trace["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - trace["busy_s"] / trace["window_s"])
