@@ -74,6 +74,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import isa
 from .costs import (DEFAULT_COSTS, I_ATOMIC, I_HIT, I_INV, I_LOCAL, I_MISS,
@@ -661,23 +662,32 @@ def _fault_fields(faults) -> dict:
 
 
 def _make_run(n_threads: int, mem_words: int, n_locks: int):
-    """While-loop driver over the single-event step for one shape set."""
+    """While-loop driver over the single-event step for one shape set.
 
-    def run(program, init_pc, init_regs, init_mem, n_active, seed,
-            horizon, max_events, costs, wa_base, wa_mask, wa_size, *faults):
+    Besides the stats it returns ``loop_iters``, the steps its loop ran: the
+    cell's events, plus any step a fault left without one.
+    """
+
+    def lockvm_cell(program, init_pc, init_regs, init_mem, n_active, seed,
+                    horizon, max_events, costs, wa_base, wa_mask, wa_size,
+                    *faults):
         c = SimConsts(program=program, costs=costs,
                       wa_base=wa_base, wa_mask=wa_mask, wa_size=wa_size,
                       horizon=horizon, max_events=max_events,
                       **_fault_fields(faults))
 
-        def cond(s: SimState):
+        def cond(carry):
+            s, _ = carry
             t_th, t_cm = _event_times(s)
             return (s.events < c.max_events) & (jnp.minimum(t_th, t_cm) < c.horizon)
 
-        final = jax.lax.while_loop(cond, functools.partial(_step, c),
-                                   _initial_state(n_threads, mem_words, n_locks,
-                                                  init_pc, init_regs, init_mem,
-                                                  n_active, seed))
+        def body(carry):
+            s, n = carry
+            return _step(c, s), n + 1
+
+        s0 = _initial_state(n_threads, mem_words, n_locks, init_pc, init_regs,
+                            init_mem, n_active, seed)
+        final, iters = jax.lax.while_loop(cond, body, (s0, jnp.int32(0)))
         return {
             "acquisitions": final.acq,
             "waited_acquisitions": final.waited_acq,
@@ -687,9 +697,10 @@ def _make_run(n_threads: int, mem_words: int, n_locks: int):
             "sleeping": (final.spin_addr >= 0).sum(),
             "grant_value": final.mem,  # full memory; callers slice what they need
             "lat_hist": final.lat_hist,
+            "loop_iters": iters,
         }
 
-    return run
+    return lockvm_cell
 
 
 def _make_run_batched(n_threads: int, mem_words: int, n_locks: int):
@@ -700,11 +711,13 @@ def _make_run_batched(n_threads: int, mem_words: int, n_locks: int):
     ``lax.while_loop`` would otherwise emit every iteration: the step is
     self-guarding (finished lanes dispatch the no-event pseudo-op and are
     exact identities), so the loop simply runs until every lane is done.
+    ``loop_iters`` counts its iterations, each one step of every lane.
     """
     n_lines = mem_words // isa.WORDS_PER_SECTOR
 
-    def run(program, init_pc, init_regs, init_mem, n_active, seed,
-            horizon, max_events, costs, wa_base, wa_mask, wa_size, *faults):
+    def lockvm_vmap(program, init_pc, init_regs, init_mem, n_active, seed,
+                    horizon, max_events, costs, wa_base, wa_mask, wa_size,
+                    *faults):
         n_cells = program.shape[0]
         c = SimConsts(program=program, costs=costs,
                       wa_base=wa_base, wa_mask=wa_mask, wa_size=wa_size,
@@ -738,13 +751,18 @@ def _make_run_batched(n_threads: int, mem_words: int, n_locks: int):
         )
         vstep = jax.vmap(_step)
 
-        def cond(s: SimState):
+        def cond(carry):
+            s, _ = carry
             t_th = s.next_time.min(1)
             t_cm = jnp.where(s.pend_addr >= 0, s.pend_time, INF).min(1)
             return jnp.any((s.events < c.max_events)
                            & (jnp.minimum(t_th, t_cm) < c.horizon))
 
-        final = jax.lax.while_loop(cond, functools.partial(vstep, c), s0)
+        def body(carry):
+            s, n = carry
+            return vstep(c, s), n + 1
+
+        final, iters = jax.lax.while_loop(cond, body, (s0, jnp.int32(0)))
         return {
             "acquisitions": final.acq,
             "waited_acquisitions": final.waited_acq,
@@ -754,9 +772,10 @@ def _make_run_batched(n_threads: int, mem_words: int, n_locks: int):
             "sleeping": (final.spin_addr >= 0).sum(1),
             "grant_value": final.mem,
             "lat_hist": final.lat_hist,
+            "loop_iters": iters,
         }
 
-    return run
+    return lockvm_vmap
 
 
 def _make_run_map(n_threads: int, mem_words: int, n_locks: int):
@@ -767,13 +786,14 @@ def _make_run_map(n_threads: int, mem_words: int, n_locks: int):
     CPU this wins: a lane-parallel sweep costs ``max(events) × B`` lane-steps
     (idle lanes still pay the switch) while the sequential map costs
     ``sum(events)`` — and scalar XLA scatters see no SIMD benefit anyway.
+    ``loop_iters`` holds each cell's own loop count, shape ``(B,)``.
     """
     run = _make_run(n_threads, mem_words, n_locks)
 
-    def run_map(*args):
+    def lockvm_map(*args):
         return jax.lax.map(lambda cell: run(*cell), args)
 
-    return run_map
+    return lockvm_map
 
 
 def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
@@ -792,10 +812,12 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
 
     A lane whose queue ran dry parks with ``lane_cell = -1`` and a zero
     horizon, making its steps free no-events until the loop ends.
+    ``loop_iters`` counts the outer bursts, each ``chunk`` steps of every lane.
     """
 
-    def run(program, init_pc, init_regs, init_mem, n_active, seed,
-            horizon, max_events, costs, wa_base, wa_mask, wa_size, *faults):
+    def lockvm_sched(program, init_pc, init_regs, init_mem, n_active, seed,
+                     horizon, max_events, costs, wa_base, wa_mask, wa_size,
+                     *faults):
         n_cells = program.shape[0]
         lanes = min(n_lanes, n_cells)
 
@@ -817,11 +839,11 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
         vstep = jax.vmap(_step)
 
         def cond(carry):
-            lane_cell, next_cell, _, _ = carry
+            lane_cell, next_cell, _, _, _ = carry
             return (next_cell < n_cells) | jnp.any(lane_cell >= 0)
 
         def body(carry):
-            lane_cell, next_cell, s, outs = carry
+            lane_cell, next_cell, s, outs, bursts = carry
             c = lane_consts(lane_cell)
             s = jax.lax.fori_loop(0, chunk, lambda _, st: vstep(c, st), s)
             # terminated lanes: exact negation of the step's ``live`` guard,
@@ -866,7 +888,7 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
                 lambda new, old: jnp.where(
                     gets.reshape((lanes,) + (1,) * (old.ndim - 1)), new, old),
                 fresh, s)
-            return lane_cell, next_cell, s, outs
+            return lane_cell, next_cell, s, outs, bursts + 1
 
         lane_cell0 = jnp.arange(lanes, dtype=jnp.int32)
         outs0 = {
@@ -880,10 +902,11 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
             "lat_hist": jnp.zeros((n_cells, N_LAT_BUCKETS), jnp.int32),
         }
         carry = (lane_cell0, jnp.int32(lanes),
-                 jax.vmap(cell_init)(lane_cell0), outs0)
-        return jax.lax.while_loop(cond, body, carry)[3]
+                 jax.vmap(cell_init)(lane_cell0), outs0, jnp.int32(0))
+        *_, outs, bursts = jax.lax.while_loop(cond, body, carry)
+        return dict(outs, loop_iters=bursts)
 
-    return run
+    return lockvm_sched
 
 
 @functools.lru_cache(maxsize=256)
@@ -901,7 +924,10 @@ def _build_engine(n_threads: int, mem_words: int, n_locks: int, prog_len: int,
     the ``interpret`` flag); either way a sweep is one compile and one
     dispatch, not one per cell.  ``n_faults`` is the fault-schedule capacity:
     0 builds the fault-free step (no fault code traced at all); > 0 drivers
-    take four trailing ``(B, n_faults)`` schedule arrays.
+    take four trailing ``(B, n_faults)`` schedule arrays.  Each driver's
+    function has a name of its own (``lockvm_cell``, ``lockvm_map``,
+    ``lockvm_vmap``, ``lockvm_sched``, ``lockvm_pallas``), which JAX's
+    compile and execution events carry.
     """
     if batched == "sched":
         assert not interpret, "interpret only applies to mode='pallas'"
@@ -972,6 +998,7 @@ def run_sim(program: np.ndarray, *, n_threads: int, mem_words: int,
                  jnp.int32(wa_base), jnp.int32(wa_size - 1),
                  jnp.int32(wa_size), *(jnp.asarray(a) for a in fault_args))
     mem = np.asarray(out.pop("grant_value"))
+    del out["loop_iters"]
     res = {k: np.asarray(v) for k, v in out.items()}
     res["mem"] = mem
     res["horizon"] = horizon
@@ -1146,8 +1173,15 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
     (B, n_threads), scalars (B,), and ``grant_value`` (B, mem_words) holds
     each cell's final memory.  Two bookkeeping keys ride along: ``mode``
     (the resolved driver, useful under "auto") and ``pad_stats`` — the
-    sweep's padding-waste report (``sum_events``/``max_events`` plus the
-    live thread/program/memory fractions of the padded batch).
+    sweep's padding-waste report (``sum_events``/``max_events``, the
+    live thread/program/memory fractions of the padded batch, and the
+    driver's ``lanes`` and ``lane_steps``).
+
+    Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``):
+    ``lockvm.dispatch`` around the upload and the jitted call, its
+    arguments the compile key; ``lockvm.readback`` around the copy of the
+    outputs to the host; ``lockvm.assemble`` around the bookkeeping, with
+    ``lanes`` and ``lane_steps`` as arguments.
     """
     programs = np.asarray(programs, np.int32)
     assert programs.ndim == 3 and programs.shape[2] == 5, programs.shape
@@ -1213,32 +1247,50 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         fault_args, n_faults = (), 0
 
     n_active_arr = _broadcast_cells(n_active, n_cells, np.int32)
-    engine = _build_engine(n_threads, mem_words, n_locks, prog_len,
-                           batched=mode, n_lanes=lanes, chunk=chunk,
-                           interpret=interpret, n_faults=n_faults)
-    out = engine(jnp.asarray(programs), jnp.asarray(init_pc),
-                 jnp.asarray(init_regs), jnp.asarray(init_mem),
-                 jnp.asarray(n_active_arr),
-                 jnp.asarray(_broadcast_cells(seeds, n_cells, np.uint32)),
-                 jnp.asarray(_broadcast_cells(horizon, n_cells, np.int32)),
-                 jnp.asarray(_broadcast_cells(max_events, n_cells, np.int32)),
-                 jnp.asarray(costs),
-                 jnp.asarray(_broadcast_cells(wa_base, n_cells, np.int32)),
-                 jnp.asarray(wa_size_arr - 1),
-                 jnp.asarray(wa_size_arr),
-                 *(jnp.asarray(a) for a in fault_args))
-    res = {k: np.asarray(v) for k, v in out.items()}
-    res["mode"] = mode
-    res["pad_stats"] = _pad_stats(
-        programs, n_active_arr, n_threads, res["events"],
-        _broadcast_cells(mem_words if live_mem_words is None
-                         else live_mem_words, n_cells, np.int64), mem_words)
+    # the span's arguments are the compile key, so a recompile shows nested
+    # under the span that names it
+    with TraceAnnotation("lockvm.dispatch", mode=mode, cells=n_cells,
+                         n_threads=n_threads, mem_words=mem_words,
+                         prog_len=prog_len, lanes=lanes, chunk=chunk,
+                         n_faults=n_faults):
+        engine = _build_engine(n_threads, mem_words, n_locks, prog_len,
+                               batched=mode, n_lanes=lanes, chunk=chunk,
+                               interpret=interpret, n_faults=n_faults)
+        out = engine(
+            jnp.asarray(programs), jnp.asarray(init_pc),
+            jnp.asarray(init_regs), jnp.asarray(init_mem),
+            jnp.asarray(n_active_arr),
+            jnp.asarray(_broadcast_cells(seeds, n_cells, np.uint32)),
+            jnp.asarray(_broadcast_cells(horizon, n_cells, np.int32)),
+            jnp.asarray(_broadcast_cells(max_events, n_cells, np.int32)),
+            jnp.asarray(costs),
+            jnp.asarray(_broadcast_cells(wa_base, n_cells, np.int32)),
+            jnp.asarray(wa_size_arr - 1),
+            jnp.asarray(wa_size_arr),
+            *(jnp.asarray(a) for a in fault_args))
+    with TraceAnnotation("lockvm.readback"):
+        res = {k: np.asarray(v) for k, v in out.items()}
+    loop_iters = res.pop("loop_iters")
+    # lanes stepped together, times the steps each ran: a loop iteration of
+    # sched or pallas is a burst of ``chunk`` steps (chunk is 0 elsewhere),
+    # and map and pallas count per cell
+    lanes_per_step = {"vmap": n_cells, "sched": min(lanes, n_cells)}.get(mode, 1)
+    lane_steps = (lanes_per_step * max(chunk, 1)
+                  * int(loop_iters.sum(dtype=np.int64)))
+    with TraceAnnotation("lockvm.assemble", lanes=lanes_per_step,
+                         lane_steps=lane_steps):
+        res["mode"] = mode
+        res["pad_stats"] = _pad_stats(
+            programs, n_active_arr, n_threads, res["events"],
+            _broadcast_cells(mem_words if live_mem_words is None
+                             else live_mem_words, n_cells, np.int64),
+            mem_words, lanes=lanes_per_step, lane_steps=lane_steps)
     return res
 
 
 def _pad_stats(programs: np.ndarray, n_active: np.ndarray, n_threads: int,
                events: np.ndarray, live_mem: np.ndarray,
-               mem_words: int) -> dict:
+               mem_words: int, *, lanes: int, lane_steps: int) -> dict:
     """Padding-waste report for one sweep dispatch.
 
     Batched cells are padded to shared shapes, and the padding is pure
@@ -1247,6 +1299,12 @@ def _pad_stats(programs: np.ndarray, n_active: np.ndarray, n_threads: int,
     table, padded memory words occupy hot state (and sharer-bitset lines).
     ``bench_engine`` and fuzz runs report these fractions so packer
     regressions are visible instead of silently eaten as wall-clock.
+
+    The driver's own waste rides along: ``lanes`` is the cells it steps
+    together and ``lane_steps`` the steps its loop ran times ``lanes``, from
+    the counter in the loop. ``sum_events / lane_steps`` is the share of
+    lane-steps that ran an event; the rest are steps of finished, parked or
+    padded lanes.
     """
     from .isa import HALT
     n_cells, prog_len = programs.shape[0], programs.shape[1]
@@ -1262,4 +1320,6 @@ def _pad_stats(programs: np.ndarray, n_active: np.ndarray, n_threads: int,
         "live_thread_frac": float(n_active.sum() / (n_cells * n_threads)),
         "live_prog_frac": float(live_rows.sum() / (n_cells * prog_len)),
         "live_mem_frac": float(live_mem.sum() / (n_cells * mem_words)),
+        "lanes": lanes,
+        "lane_steps": lane_steps,
     }

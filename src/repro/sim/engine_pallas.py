@@ -76,7 +76,9 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
     ``n_faults`` — when > 0 the driver takes four trailing ``(B, n_faults)``
     fault-schedule arrays and the kernel's step gains the fault phase (the
     no-event identity still holds for overshoot steps: faults only apply
-    while the cell is live, so burst overshoot remains free).
+    while the cell is live, so burst overshoot remains free).  Besides
+    :data:`OUT_KEYS` it returns ``loop_iters``, each cell's bursts of
+    ``chunk`` steps, shape ``(B,)``.
     """
     assert chunk >= 1, chunk
     n_lines = mem_words // isa.WORDS_PER_SECTOR
@@ -91,11 +93,11 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
         Refs hold this cell's (1, ...) blocks; indexing row 0 materializes
         the cell's state in kernel memory, where the whole event burst runs
         before the final stats are stored back.  ``rest`` is the four fault
-        refs (when ``n_faults > 0``) followed by the eight output refs.
+        refs (when ``n_faults > 0``) followed by the nine output refs.
         """
-        fault_refs, out_refs = rest[:-8], rest[-8:]
+        fault_refs, out_refs = rest[:-9], rest[-9:]
         (acq_ref, wacq_ref, hs_ref, hc_ref, ev_ref, slp_ref, mem_ref,
-         lh_ref) = out_refs
+         lh_ref, bursts_ref) = out_refs
         fault_fields = {}
         if fault_refs:
             fault_fields = dict(zip(
@@ -109,17 +111,20 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
                             init_pc_ref[0], init_regs_ref[0],
                             init_mem_ref[0], n_active_ref[0], seed_ref[0])
 
-        def live(s):
+        def live(carry):
             # exactly the single-cell driver's loop condition
+            s, _ = carry
             t_th = jnp.min(s.next_time)
             t_cm = jnp.min(jnp.where(s.pend_addr >= 0, s.pend_time, INF))
             return (s.events < c.max_events) & \
                 (jnp.minimum(t_th, t_cm) < c.horizon)
 
-        def burst(s):
-            return jax.lax.fori_loop(0, chunk, lambda _, st: _step(c, st), s)
+        def burst(carry):
+            s, n = carry
+            s = jax.lax.fori_loop(0, chunk, lambda _, st: _step(c, st), s)
+            return s, n + 1
 
-        s = jax.lax.while_loop(live, burst, s0)
+        s, bursts = jax.lax.while_loop(live, burst, (s0, jnp.int32(0)))
         acq_ref[0] = s.acq
         wacq_ref[0] = s.waited_acq
         hs_ref[0] = s.hand_sum
@@ -128,9 +133,11 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
         slp_ref[0] = (s.spin_addr >= 0).sum().astype(jnp.int32)
         mem_ref[0] = s.mem
         lh_ref[0] = s.lat_hist
+        bursts_ref[0] = bursts
 
-    def run(program, init_pc, init_regs, init_mem, n_active, seed,
-            horizon, max_events, costs, wa_base, wa_mask, wa_size, *faults):
+    def lockvm_pallas(program, init_pc, init_regs, init_mem, n_active, seed,
+                      horizon, max_events, costs, wa_base, wa_mask, wa_size,
+                      *faults):
         assert len(faults) == (4 if n_faults else 0), \
             (len(faults), n_faults)
         n_cells = program.shape[0]
@@ -159,6 +166,7 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
                 #                                            events, sleeping
                 pl.BlockSpec((1, mem_words), cell2),       # grant_value
                 pl.BlockSpec((1, N_LAT_BUCKETS), cell2),   # lat_hist
+                scalar,                                    # loop_iters
             ],
             out_shape=[
                 jax.ShapeDtypeStruct((n_cells, n_threads), i32),
@@ -169,10 +177,11 @@ def make_run_pallas(n_threads: int, mem_words: int, n_locks: int,
                 jax.ShapeDtypeStruct((n_cells,), i32),
                 jax.ShapeDtypeStruct((n_cells, mem_words), i32),
                 jax.ShapeDtypeStruct((n_cells, N_LAT_BUCKETS), i32),
+                jax.ShapeDtypeStruct((n_cells,), i32),
             ],
             interpret=interpret,
         )(program, init_pc, init_regs, init_mem, n_active, seed,
           horizon, max_events, costs, wa_base, wa_mask, wa_size, *faults)
-        return dict(zip(OUT_KEYS, out))
+        return dict(zip(OUT_KEYS + ("loop_iters",), out))
 
-    return run
+    return lockvm_pallas

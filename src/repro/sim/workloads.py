@@ -17,6 +17,7 @@ import os
 from dataclasses import dataclass, replace
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from . import engine
 from .costs import DEFAULT_COSTS, Costs
@@ -113,6 +114,21 @@ class SweepSpec:
     #                                      cs_work/outside_work axes (see
     #                                      repro.sim.traces.trace_sweep_spec)
 
+    def _axes(self) -> list[tuple]:
+        return [_as_tuple(self.locks), _as_tuple(self.threads),
+                _as_tuple(self.seeds), _as_tuple(self.cs_work),
+                _as_tuple(self.outside_work), _as_tuple(self.private_arrays),
+                _as_tuple(self.costs), _as_tuple(self.wa_size),
+                _as_tuple(self.long_term_threshold),
+                _as_tuple(self.sem_permits), _as_tuple(self.reader_fraction),
+                _as_tuple(self.preempt_faults),
+                _as_tuple(self.spurious_faults),
+                _as_tuple(self.abort_faults)]
+
+    def n_cells(self) -> int:
+        """``len(self.cells())``, without building them."""
+        return math.prod(len(axis) for axis in self._axes())
+
     def cells(self) -> list[SweepCell]:
         return [SweepCell(lock=lk, n_threads=t, seed=s, cs_work=cw,
                           outside_work=ow, private_arrays=pa, costs=co,
@@ -120,18 +136,7 @@ class SweepSpec:
                           reader_fraction=rf, preempt_faults=pf,
                           spurious_faults=sf, abort_faults=af)
                 for lk, t, s, cw, ow, pa, co, ws, lt, sp, rf, pf, sf, af
-                in itertools.product(
-                    _as_tuple(self.locks), _as_tuple(self.threads),
-                    _as_tuple(self.seeds), _as_tuple(self.cs_work),
-                    _as_tuple(self.outside_work),
-                    _as_tuple(self.private_arrays), _as_tuple(self.costs),
-                    _as_tuple(self.wa_size),
-                    _as_tuple(self.long_term_threshold),
-                    _as_tuple(self.sem_permits),
-                    _as_tuple(self.reader_fraction),
-                    _as_tuple(self.preempt_faults),
-                    _as_tuple(self.spurious_faults),
-                    _as_tuple(self.abort_faults))]
+                in itertools.product(*self._axes())]
 
     def fault_schedule_for(self, cell: SweepCell) -> FaultSchedule:
         """The cell's deterministic fault schedule (empty when all axes 0).
@@ -179,109 +184,130 @@ def run_sweep(spec: SweepSpec, *, mode: str = "auto",
     ``"auto"`` picks per backend + sweep shape; ``lanes``/``chunk``
     configure the ``"sched"`` work-stealing driver, ``chunk``/``interpret``
     the ``"pallas"`` fused kernel); results are mode-independent.
+
+    Host spans on the profiler's clock, nested in ``lockvm.sweep``: the
+    cells' programs, layouts and fault schedules (``lockvm.build``), their
+    padding and stacking (``lockvm.pack``), the engine's own spans, the
+    per-cell dicts (``lockvm.assemble``) and the results-store append
+    (``lockvm.store``).
     """
-    cells = spec.cells()
-    built = []
-    for cell in cells:
-        layout = spec.layout_for(cell)
-        if spec.trace is not None:
-            # Trace-compiled cell: CS/outside work come from the recorded
-            # distribution tables, not the scalar axes (which the spec pins
-            # to the trace's representative values for coordinate purposes).
-            from .traces import (build_trace_bench, trace_init_mem,
-                                 trace_layout_for)
-            layout = trace_layout_for(spec.trace, layout)
-            prog = build_trace_bench(cell.lock, layout, spec.trace,
-                                     collect_latency=spec.collect_latency)
-            pc, regs = init_state(layout)
-            init_mem = trace_init_mem(cell.lock, layout, spec.trace)
-        else:
-            prog = build_mutexbench(cell.lock, layout, cs_work=cell.cs_work,
-                                    ncs_max=spec.ncs_max, cs_rand=spec.cs_rand,
-                                    outside_work=cell.outside_work,
-                                    collect_latency=spec.collect_latency)
-            pc, regs = init_state(layout)
-            gen_mem = INIT_MEM_GEN.get(cell.lock)
-            init_mem = (gen_mem(layout) if gen_mem
-                        else np.zeros(layout.mem_words, np.int32))
-        built.append((layout, prog, pc, regs, init_mem))
-
-    t_max = max(layout.n_threads for layout, *_ in built)
-    m_max = max(layout.mem_words for layout, *_ in built)
-    padded = [pad_threads(pc, regs, t_max) for _, _, pc, regs, _ in built]
-    scheds = [spec.fault_schedule_for(cell) for cell in cells]
-    # faults=None when no cell schedules any fault: the engine call (and
-    # its compiled kernel) is then byte-identical to the pre-fault path.
-    faults = (stack_schedules(scheds) if any(len(s) for s in scheds)
-              else None)
-    raw = engine.run_sweep(
-        np.stack([pad_program(prog) for _, prog, *_ in built]),
-        mem_words=m_max, n_locks=spec.n_locks,
-        init_pc=np.stack([pc for pc, _ in padded]),
-        init_regs=np.stack([regs for _, regs in padded]),
-        n_active=np.asarray([layout.n_threads for layout, *_ in built]),
-        seeds=np.asarray([cell.seed for cell in cells], np.uint32),
-        wa_base=np.asarray([layout.wa_base for layout, *_ in built]),
-        wa_size=np.asarray([layout.wa_size for layout, *_ in built]),
-        horizon=spec.horizon,
-        max_events=spec.max_events,
-        costs=np.stack([cell.costs.to_array() for cell in cells]),
-        init_mem=np.stack([pad_mem(init_mem, m_max)
-                           for *_, init_mem in built]),
-        mode=mode, lanes=lanes, chunk=chunk, interpret=interpret,
-        live_mem_words=np.asarray([layout.mem_words
-                                   for layout, *_ in built]),
-        faults=faults,
-    )
-
-    results = []
-    for i, (cell, (layout, *_)) in enumerate(zip(cells, built)):
-        t = layout.n_threads
-        res = {
-            "lock": cell.lock, "n_threads": t, "seed": cell.seed,
-            "cs_work": cell.cs_work, "outside_work": cell.outside_work,
-            "private_arrays": cell.private_arrays,
-            "costs": cell.costs, "wa_size": cell.wa_size,
-            "long_term_threshold": cell.long_term_threshold,
-            "sem_permits": cell.sem_permits,
-            "reader_fraction": cell.reader_fraction,
-            "preempt_faults": cell.preempt_faults,
-            "spurious_faults": cell.spurious_faults,
-            "abort_faults": cell.abort_faults,
-            "fault_schedule": scheds[i],
-            "layout": layout,  # the run's OWN layout (collision readers
-            #                    must not reconstruct it by hand)
-            "acquisitions": raw["acquisitions"][i, :t],
-            "waited_acquisitions": raw["waited_acquisitions"][i, :t],
-            "handover_sum": raw["handover_sum"][i],
-            "handover_count": raw["handover_count"][i],
-            "events": raw["events"][i],
-            "sleeping": raw["sleeping"][i],
-            "mem": raw["grant_value"][i, :layout.mem_words],
-            "horizon": spec.horizon,
-            "n_locks": spec.n_locks,
-            "mode": raw["mode"],          # resolved driver (mode="auto")
-            "pad_stats": raw["pad_stats"],  # sweep-wide padding waste
-            "workload": (f"trace:{spec.trace.name}" if spec.trace is not None
-                         else "synthetic"),
-        }
-        res["throughput"] = float(res["acquisitions"].sum()) / spec.horizon
-        hc = int(res["handover_count"])
-        res["avg_handover"] = (float(res["handover_sum"]) / hc if hc
-                               else float("nan"))
-        if spec.collect_latency:
-            hist = np.asarray(raw["lat_hist"][i])
-            res["lat_hist"] = hist
-            res["lat_p50"] = hist_percentile(hist, 0.5)
-            res["lat_p99"] = hist_percentile(hist, 0.99)
-            res["lat_p999"] = hist_percentile(hist, 0.999)
-        results.append(res)
-
-    store_path = os.environ.get(RESULTS_STORE_ENV)
-    if store_path:
-        from .results.store import ResultsStore
-        ResultsStore(store_path).append_sweep(results)
+    with TraceAnnotation("lockvm.sweep", cells=spec.n_cells(), mode=mode):
+        with TraceAnnotation("lockvm.build"):
+            cells = spec.cells()
+            built = [_build_cell(spec, cell) for cell in cells]
+            scheds = [spec.fault_schedule_for(cell) for cell in cells]
+            # faults=None when no cell schedules any fault: the engine call
+            # (and its compiled kernel) is then byte-identical to the
+            # pre-fault path.
+            faults = (stack_schedules(scheds) if any(len(s) for s in scheds)
+                      else None)
+        with TraceAnnotation("lockvm.pack"):
+            t_max = max(layout.n_threads for layout, *_ in built)
+            m_max = max(layout.mem_words for layout, *_ in built)
+            padded = [pad_threads(pc, regs, t_max)
+                      for _, _, pc, regs, _ in built]
+            programs = np.stack([pad_program(prog) for _, prog, *_ in built])
+            init_pc = np.stack([pc for pc, _ in padded])
+            init_regs = np.stack([regs for _, regs in padded])
+            costs = np.stack([cell.costs.to_array() for cell in cells])
+            init_mem = np.stack([pad_mem(init_mem, m_max)
+                                 for *_, init_mem in built])
+            layouts = [layout for layout, *_ in built]
+            n_active = np.asarray([layout.n_threads for layout in layouts])
+            seeds = np.asarray([cell.seed for cell in cells], np.uint32)
+            wa_base = np.asarray([layout.wa_base for layout in layouts])
+            wa_size = np.asarray([layout.wa_size for layout in layouts])
+            live_mem_words = np.asarray([layout.mem_words
+                                         for layout in layouts])
+        raw = engine.run_sweep(
+            programs, mem_words=m_max, n_locks=spec.n_locks,
+            init_pc=init_pc, init_regs=init_regs, n_active=n_active,
+            seeds=seeds, wa_base=wa_base, wa_size=wa_size,
+            horizon=spec.horizon, max_events=spec.max_events,
+            costs=costs, init_mem=init_mem,
+            mode=mode, lanes=lanes, chunk=chunk, interpret=interpret,
+            live_mem_words=live_mem_words, faults=faults,
+        )
+        with TraceAnnotation("lockvm.assemble"):
+            results = [_result_row(spec, cell, layouts[i], scheds[i], raw, i)
+                       for i, cell in enumerate(cells)]
+        store_path = os.environ.get(RESULTS_STORE_ENV)
+        if store_path:
+            with TraceAnnotation("lockvm.store"):
+                from .results.store import ResultsStore
+                ResultsStore(store_path).append_sweep(results)
     return results
+
+
+def _build_cell(spec: SweepSpec, cell: SweepCell) -> tuple:
+    """One cell's ``(layout, program, init_pc, init_regs, init_mem)``."""
+    layout = spec.layout_for(cell)
+    if spec.trace is not None:
+        # Trace-compiled cell: CS/outside work come from the recorded
+        # distribution tables, not the scalar axes (which the spec pins
+        # to the trace's representative values for coordinate purposes).
+        from .traces import (build_trace_bench, trace_init_mem,
+                             trace_layout_for)
+        layout = trace_layout_for(spec.trace, layout)
+        prog = build_trace_bench(cell.lock, layout, spec.trace,
+                                 collect_latency=spec.collect_latency)
+        pc, regs = init_state(layout)
+        init_mem = trace_init_mem(cell.lock, layout, spec.trace)
+    else:
+        prog = build_mutexbench(cell.lock, layout, cs_work=cell.cs_work,
+                                ncs_max=spec.ncs_max, cs_rand=spec.cs_rand,
+                                outside_work=cell.outside_work,
+                                collect_latency=spec.collect_latency)
+        pc, regs = init_state(layout)
+        gen_mem = INIT_MEM_GEN.get(cell.lock)
+        init_mem = (gen_mem(layout) if gen_mem
+                    else np.zeros(layout.mem_words, np.int32))
+    return layout, prog, pc, regs, init_mem
+
+
+def _result_row(spec: SweepSpec, cell: SweepCell, layout: Layout,
+                sched: FaultSchedule, raw: dict, i: int) -> dict:
+    """Cell ``i``'s result dict from the engine's stacked outputs."""
+    t = layout.n_threads
+    res = {
+        "lock": cell.lock, "n_threads": t, "seed": cell.seed,
+        "cs_work": cell.cs_work, "outside_work": cell.outside_work,
+        "private_arrays": cell.private_arrays,
+        "costs": cell.costs, "wa_size": cell.wa_size,
+        "long_term_threshold": cell.long_term_threshold,
+        "sem_permits": cell.sem_permits,
+        "reader_fraction": cell.reader_fraction,
+        "preempt_faults": cell.preempt_faults,
+        "spurious_faults": cell.spurious_faults,
+        "abort_faults": cell.abort_faults,
+        "fault_schedule": sched,
+        "layout": layout,  # the run's OWN layout (collision readers
+        #                    must not reconstruct it by hand)
+        "acquisitions": raw["acquisitions"][i, :t],
+        "waited_acquisitions": raw["waited_acquisitions"][i, :t],
+        "handover_sum": raw["handover_sum"][i],
+        "handover_count": raw["handover_count"][i],
+        "events": raw["events"][i],
+        "sleeping": raw["sleeping"][i],
+        "mem": raw["grant_value"][i, :layout.mem_words],
+        "horizon": spec.horizon,
+        "n_locks": spec.n_locks,
+        "mode": raw["mode"],          # resolved driver (mode="auto")
+        "pad_stats": raw["pad_stats"],  # sweep-wide padding waste
+        "workload": (f"trace:{spec.trace.name}" if spec.trace is not None
+                     else "synthetic"),
+    }
+    res["throughput"] = float(res["acquisitions"].sum()) / spec.horizon
+    hc = int(res["handover_count"])
+    res["avg_handover"] = (float(res["handover_sum"]) / hc if hc
+                           else float("nan"))
+    if spec.collect_latency:
+        hist = np.asarray(raw["lat_hist"][i])
+        res["lat_hist"] = hist
+        res["lat_p50"] = hist_percentile(hist, 0.5)
+        res["lat_p99"] = hist_percentile(hist, 0.99)
+        res["lat_p999"] = hist_percentile(hist, 0.999)
+    return res
 
 
 # Environment hook: when set, every run_sweep() appends its result rows to
