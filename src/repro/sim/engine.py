@@ -30,13 +30,19 @@ Structure (batched-sweep refactor):
   * :func:`_step` — pure single-event transition ``(SimConsts, SimState) ->
     SimState``.  Event selection is ONE fused argmin over the concatenated
     ``[pending-commit times | thread times]`` vector (ties resolve to the
-    commit, matching the historical ``t_cm <= t_th`` rule).  The opcode
-    switch computes only a compact :class:`Effects` record (scalars plus one
-    register row); the big-array updates (memory, sharer bitsets, pending
-    stores, wakeups) are applied ONCE outside the switch.  This matters
-    under ``vmap``: a batched ``lax.switch`` executes every branch and
-    selects, so branches must not carry whole-state copies.  A store commit
-    is dispatched through the same switch as pseudo-opcode ``isa.N_OPS``.
+    commit, matching the historical ``t_cm <= t_th`` rule).  The step's
+    operands (instruction, register row, the memory word, sharer row and
+    dirty owner at the one effective address) are fetched once before the
+    opcode switch; the switch computes only a compact :class:`Effects`
+    record of scalars; every state update (registers, memory, sharer
+    bitsets, pending stores, wakeups) is applied ONCE after it.  This
+    matters under ``vmap``: a batched ``lax.switch`` executes every branch
+    and selects, so branches must not carry whole-state copies.  Reads and
+    writes go by one-hot masks, not indices, since under ``vmap`` an index
+    is a per-lane gather or scatter.  Memory and its lines are the
+    exception: on a TPU up to :data:`DENSE_MEM_WORDS` words they go by mask
+    too, otherwise by one index per array.  A store commit is dispatched
+    through the same switch as pseudo-opcode ``isa.N_OPS``.
   * :func:`_make_run` — wraps the step in a ``lax.while_loop`` driver plus
     stats extraction.
   * :func:`_build_engine` — lru-cached jit of the driver, keyed ONLY on array
@@ -169,7 +175,7 @@ class SimState(NamedTuple):
 
 
 class Effects(NamedTuple):
-    """What one event does, in O(1) scalars plus the actor's register row.
+    """What one event does, in O(1) scalars.
 
     Every switch branch returns one of these; the apply phase in
     :func:`_step` turns it into state updates.  "actor" is the executing
@@ -179,7 +185,8 @@ class Effects(NamedTuple):
 
     cost: jax.Array        # charged to the actor (advancing events only)
     new_pc: jax.Array
-    reg_row: jax.Array     # (N_REGS,) the actor's registers after the event
+    reg_dst: jax.Array     # actor's register to write, -1 = none
+    reg_val: jax.Array
     prng_t: jax.Array      # actor's PRNG state after the event
     sleep: jax.Array       # bool — park the actor (next_time = INF)
     advance: jax.Array     # bool — update the actor's pc/regs/prng/next_time
@@ -189,9 +196,9 @@ class Effects(NamedTuple):
     clear_pend: jax.Array  # bool — a commit consumed the actor's pending store
     w_addr: jax.Array      # immediate memory write (RMW/commit), -1 = none
     w_val: jax.Array
-    excl_ln: jax.Array     # line that became exclusive to the actor, -1 = none
-    share_ln: jax.Array    # line the actor registered as a sharer of, -1
-    downgrade: jax.Array   # bool — dirty[share_ln] = -1 (foreign dirty read)
+    excl: jax.Array        # bool — the step's line became exclusive to the actor
+    share: jax.Array       # bool — the actor registered as a sharer of the line
+    downgrade: jax.Array   # bool — the line's dirty owner := -1 (foreign dirty read)
     park_addr: jax.Array   # actor parks watching this address, -1 = none
     wake_addr: jax.Array   # wake watchers of this address, -1 = none
     wake_time: jax.Array
@@ -212,15 +219,86 @@ def _event_times(s: SimState):
     return t_th, t_cm
 
 
+def _clamped(i, n: int):
+    """A dynamic index as ``x[i]`` reads it: one negative wrap, then clamp."""
+    return jnp.clip(jnp.where(i < 0, i + n, i), 0, n - 1)
+
+
+def _pick(x, hit):
+    """``x[i]`` along axis 0, given the one-hot mask ``hit = (iota == i)``."""
+    return jnp.where(hit.reshape(hit.shape + (1,) * (x.ndim - 1)), x,
+                     0).sum(0, dtype=x.dtype)
+
+
+# Memory and its lines are the one state whose size the configuration sets
+# (Figure 2's private arrays hold 4 096 words per lock).  On a TPU, up to
+# this many words, a step reads and writes them by mask like every other
+# array: an index there is a per-lane gather or scatter, dearer than a
+# dense pass.  Past it, and on every other backend, where an index costs
+# little and a pass over memory a lot, ``mem``, ``sharers`` and ``dirty``
+# take one indexed read and one indexed write each.  On a v5e the two
+# forms cross between 19 712 and 37 376 words at 4 lanes, and past 37 376
+# at 40.
+DENSE_MEM_WORDS = 1 << 15
+
+
+def _read_at(x, i, dense: bool):
+    """``x[i]`` along axis 0, by mask or by index."""
+    n = x.shape[0]
+    i = _clamped(i, n)
+    return _pick(x, jnp.arange(n) == i) if dense else x[i]
+
+
+def _write_at(x, i, v, dense: bool):
+    """``x`` with entry ``i`` along axis 0 set to ``v``; an ``i`` outside
+    ``[0, n)`` writes nothing."""
+    n = x.shape[0]
+    if dense:
+        hit = jnp.arange(n) == i
+        return jnp.where(hit.reshape(hit.shape + (1,) * (x.ndim - 1)), v, x)
+    return x.at[jnp.where(i >= 0, i, n)].set(v, mode="drop")
+
+
 def _step(c: SimConsts, s: SimState) -> SimState:
-    """Advance the simulation by exactly one event (commit or thread op)."""
+    """Advance the simulation by exactly one event (commit or thread op).
+
+    Index-free: every read of a thread's or lock's entry is a masked
+    reduction over ``iota == i`` and every write a
+    ``jnp.where(iota == i, v, x)``.  Under ``vmap`` an index is a gather or
+    scatter that walks the lanes one after another; a mask is dense
+    elementwise work.  Memory and its lines go by mask too on a TPU, up to
+    :data:`DENSE_MEM_WORDS` words, and by one index per array otherwise;
+    both forms give the same state bit for bit.
+    """
+    if s.mem.shape[0] > DENSE_MEM_WORDS:
+        return _step_in(c, s, dense=False)
+    return jax.lax.platform_dependent(
+        c, s, tpu=functools.partial(_step_in, dense=True),
+        default=functools.partial(_step_in, dense=False))
+
+
+def _step_in(c: SimConsts, s: SimState, dense: bool) -> SimState:
+    """:func:`_step`, with memory and its lines read and written by mask
+    (``dense``) or by index."""
     n_threads = s.next_time.shape[0]
+    n_words = s.sharers.shape[1]
     C = c.costs
 
     (next_time, pc, regs, prng, mem, sharers, dirty,
      pend_addr, pend_val, pend_time, spin_addr, wake_delay,
      acq, waited_acq, rel_time, hand_sum, hand_cnt, events,
      acq_t0, lat_hist) = s
+    # the one-hot masks' index vectors
+    thr = jnp.arange(n_threads)
+    word_ids = jnp.arange(n_words)
+    lock_ids = jnp.arange(rel_time.shape[0])
+    reg_ids = jnp.arange(isa.N_REGS)
+
+    def bit_row(u):
+        """Thread ``u``'s bitset row: bit ``u & 31`` of word ``u >> 5``."""
+        return jnp.where(word_ids == u >> 5,
+                         jnp.uint32(1) << (u & 31).astype(jnp.uint32),
+                         jnp.uint32(0))
 
     # ---- fault phase (statically absent when no schedule is attached) ----
     # Entries matching the current event counter mutate the thread timelines
@@ -228,10 +306,10 @@ def _step(c: SimConsts, s: SimState) -> SimState:
     # finished/stalled lane never advances ``events``, so its remaining
     # schedule can never fire (and the no-event identity is preserved for
     # the batched drivers' overshoot steps).  Schedules carry unique event
-    # indices, so at most one entry applies per step and scatter order is
-    # irrelevant.  Post-fault, the normal selection below runs: if the fault
-    # pushed every timeline past the horizon, the step dispatches no-event
-    # and the counter stays put (the mutations themselves persist).
+    # indices, so at most one entry applies per step.  Post-fault, the
+    # normal selection below runs: if the fault pushed every timeline past
+    # the horizon, the step dispatches no-event and the counter stays put
+    # (the mutations themselves persist).
     fault_on = c.f_kind is not None
     if fault_on:
         ptimes0 = jnp.where(pend_addr >= 0, pend_time, INF)
@@ -239,14 +317,21 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         flive = (events < c.max_events) & (pre_min < c.horizon)
         hit = flive & (c.f_kind != 0) & (c.f_evt == events)
         running = next_time < INF
+        # each entry's thread as a one-hot row of an (n_faults, T) mask
+        f_rows = (jnp.where(c.f_tid < 0, c.f_tid + n_threads, c.f_tid)[:, None]
+                  == thr)
+
+        def per_thread(v):
+            return jnp.where(f_rows, v[:, None], 0).sum(0)
+
         # preemption: a running thread's timeline slips K; a parked/halted
         # thread instead owes K at its next wake (wake_delay)
-        k_add = jnp.zeros(n_threads, jnp.int32).at[c.f_tid].add(
-            jnp.where(hit & (c.f_kind == F_PREEMPT), c.f_arg, 0))
+        k_add = per_thread(jnp.where(hit & (c.f_kind == F_PREEMPT),
+                                     c.f_arg, 0))
         next_time = next_time + jnp.where(running, k_add, 0)
         wake_delay = wake_delay + jnp.where(running, 0, k_add)
         # spurious wake: a parked thread resumes (pc still on the SPIN op)
-        spur = jnp.zeros(n_threads, jnp.int32).at[c.f_tid].add(
+        spur = per_thread(
             (hit & (c.f_kind == F_SPURIOUS)).astype(jnp.int32)) > 0
         spur = spur & (spin_addr >= 0)
         next_time = jnp.where(spur, pre_min + C[I_WAKE] + wake_delay,
@@ -254,7 +339,7 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         wake_delay = jnp.where(spur, 0, wake_delay)
         spin_addr = jnp.where(spur, -1, spin_addr)
         # abort: dead forever — not parked (spin_addr = -1), never woken
-        dead = jnp.zeros(n_threads, jnp.int32).at[c.f_tid].add(
+        dead = per_thread(
             (hit & (c.f_kind == F_ABORT)).astype(jnp.int32)) > 0
         next_time = jnp.where(dead, INF, next_time)
         spin_addr = jnp.where(dead, -1, spin_addr)
@@ -264,39 +349,51 @@ def _step(c: SimConsts, s: SimState) -> SimState:
     # halves lands in the commit half (first occurrence), preserving the
     # historical ``t_cm <= t_th`` commit-wins rule bit for bit.
     ptimes = jnp.where(pend_addr >= 0, pend_time, INF)
-    k = jnp.argmin(jnp.concatenate([ptimes, next_time])).astype(jnp.int32)
+    both = jnp.concatenate([ptimes, next_time])
+    k = jnp.argmin(both).astype(jnp.int32)
     is_commit = k < n_threads
     tc = jnp.minimum(k, n_threads - 1)          # commit thread (dead if op)
     t = jnp.where(is_commit, 0, k - n_threads)  # op thread (dead if commit)
-    t_min = jnp.where(is_commit, ptimes[tc], next_time[t])
+    t_min = jnp.min(both)
     # Self-guarding: a lane past its horizon / event budget dispatches the
     # no-event pseudo-op, making the whole step an identity.  The unbatched
     # driver's loop condition never lets this fire; the batched drivers rely
     # on it so lanes that finish early idle for free (no per-lane select).
     live = (events < c.max_events) & (t_min < c.horizon)
-
     now = t_min
-    instr = c.program[pc[t]]
+
+    # ---- operand fetch: each read of the step happens once, here ---------
+    is_t = thr == t
+    pc_t = _pick(pc, is_t)
+    row = _pick(regs, is_t)
+    prog_len = c.program.shape[0]
+    instr = _pick(c.program, jnp.arange(prog_len) == _clamped(pc_t, prog_len))
     op, a, b, cc, imm = instr[0], instr[1], instr[2], instr[3], instr[4]
-    ra, rb, rc = regs[t, a], regs[t, b], regs[t, cc]
-    pc1 = pc[t] + 1
-    t_bit = jnp.uint32(1) << (t & 31).astype(jnp.uint32)
-    t_word = t >> 5
-
-    def load_cost(ln):
-        mine = (sharers[ln, t_word] & t_bit) > 0
-        d = dirty[ln]
-        return jnp.where(mine, C[I_HIT],
-                         jnp.where((d >= 0) & (d != t), C[I_XFER], C[I_MISS]))
-
-    def store_cost(ln, atomic):
-        row = sharers[ln]
-        total = jax.lax.population_count(row).sum().astype(jnp.int32)
-        mine = ((row[t_word] & t_bit) > 0).astype(jnp.int32)
-        others = total - mine
-        only = (mine > 0) & (others == 0)
-        cost = jnp.where(only, C[I_ST_OWNED], C[I_ST_SHARED] + C[I_INV] * others)
-        return (cost + jnp.where(atomic, C[I_ATOMIC], 0)).astype(jnp.int32)
+    ra, rb, rc = (_pick(row, reg_ids == _clamped(r, isa.N_REGS))
+                  for r in (a, b, cc))
+    # every register write goes to field a, with a scatter's semantics: one
+    # negative wrap, and an index still out of range writes nothing
+    a_w = jnp.where(a < 0, a + isa.N_REGS, a)
+    dst = jnp.where((a_w >= 0) & (a_w < isa.N_REGS), a_w, -1)
+    pc1 = pc_t + 1
+    # the one effective address: the pending store's for a commit, ra + imm
+    # for a store, rb + imm otherwise (loads, RMWs, spins)
+    is_tc = thr == tc
+    is_store = (op == isa.STORE) | (op == isa.STOREI)
+    addr = jnp.where(is_commit, _pick(pend_addr, is_tc),
+                     jnp.where(is_store, ra, rb) + imm)
+    ln = addr >> isa.LINE_SHIFT
+    m_val = _read_at(mem, addr, dense)
+    ln_row = _read_at(sharers, ln, dense)
+    d = _read_at(dirty, ln, dense)
+    mine = ((ln_row & bit_row(t)) > 0).any()
+    foreign_dirty = (d >= 0) & (d != t)
+    load_cost = jnp.where(mine, C[I_HIT],
+                          jnp.where(foreign_dirty, C[I_XFER], C[I_MISS]))
+    others = (jax.lax.population_count(ln_row).sum().astype(jnp.int32)
+              - mine.astype(jnp.int32))
+    store_cost = jnp.where(mine & (others == 0), C[I_ST_OWNED],
+                           C[I_ST_SHARED] + C[I_INV] * others)
 
     i32 = functools.partial(jnp.asarray, dtype=jnp.int32)
     none = i32(-1)
@@ -304,11 +401,11 @@ def _step(c: SimConsts, s: SimState) -> SimState:
     no = jnp.zeros((), bool)
     yes = jnp.ones((), bool)
     default = Effects(
-        cost=C[I_LOCAL], new_pc=pc1, reg_row=regs[t], prng_t=prng[t],
-        sleep=no, advance=yes,
+        cost=C[I_LOCAL], new_pc=pc1, reg_dst=none, reg_val=zero,
+        prng_t=_pick(prng, is_t), sleep=no, advance=yes,
         st_addr=none, st_val=zero, st_time=zero, clear_pend=no,
-        w_addr=none, w_val=zero, excl_ln=none,
-        share_ln=none, downgrade=no, park_addr=none,
+        w_addr=none, w_val=zero, excl=no,
+        share=no, downgrade=no, park_addr=none,
         wake_addr=none, wake_time=zero,
         acq_inc=no, waited_inc=no, hand_add=zero, hand_inc=no,
         rel_idx=none, rel_val=zero, t0_new=i32(-2), lat_idx=none)
@@ -317,50 +414,39 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         return default
 
     def h_load():
-        addr = rb + imm
-        ln = addr >> isa.LINE_SHIFT
-        mine = (sharers[ln, t_word] & t_bit) > 0
-        d = dirty[ln]
         return default._replace(
-            cost=load_cost(ln),
-            reg_row=regs[t].at[a].set(mem[addr]),
-            share_ln=ln,
-            downgrade=(~mine) & (d >= 0) & (d != t))
+            cost=load_cost, reg_dst=dst, reg_val=m_val, share=yes,
+            downgrade=(~mine) & foreign_dirty)
 
-    def _store(addr, val):
-        ln = addr >> isa.LINE_SHIFT
-        cost = store_cost(ln, False)
-        return default._replace(cost=cost, st_addr=addr, st_val=val,
-                                st_time=now + cost)
+    def _store(val):
+        return default._replace(cost=store_cost, st_addr=addr, st_val=val,
+                                st_time=now + store_cost)
 
     def h_store():
-        return _store(ra + imm, rb)
+        return _store(rb)
 
     def h_storei():
-        return _store(ra + imm, b)
+        return _store(b)
 
-    def _rmw(addr, new_val, dst_old):
+    def _rmw(new_val):
         """Immediate atomic RMW: apply, invalidate, wake watchers."""
-        ln = addr >> isa.LINE_SHIFT
-        cost = store_cost(ln, True)
-        old = mem[addr]
+        cost = store_cost + C[I_ATOMIC]
         return default._replace(
-            cost=cost,
-            reg_row=regs[t].at[dst_old].set(old),
-            w_addr=addr, w_val=i32(new_val(old)),
-            excl_ln=ln, wake_addr=addr, wake_time=now + cost)
+            cost=cost, reg_dst=dst, reg_val=m_val,
+            w_addr=addr, w_val=i32(new_val),
+            excl=yes, wake_addr=addr, wake_time=now + cost)
 
     def h_fadd():
-        return _rmw(rb + imm, lambda old: old + cc, a)
+        return _rmw(m_val + cc)
 
     def h_swap():
-        return _rmw(rb + imm, lambda old: rc, a)
+        return _rmw(rc)
 
     def h_casz():
-        return _rmw(rb + imm, lambda old: jnp.where(old == rc, 0, old), a)
+        return _rmw(jnp.where(m_val == rc, 0, m_val))
 
     def _alu(value):
-        return default._replace(reg_row=regs[t].at[a].set(value))
+        return default._replace(reg_dst=dst, reg_val=i32(value))
 
     def h_addi():
         return _alu(rb + imm)
@@ -423,35 +509,30 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         return default._replace(cost=jnp.maximum(ra, 1))
 
     def h_prng():
-        sd = prng[t] * jnp.uint32(1664525) + jnp.uint32(1013904223)
+        sd = default.prng_t * jnp.uint32(1664525) + jnp.uint32(1013904223)
         val = ((sd >> jnp.uint32(16)).astype(jnp.int32)) % jnp.maximum(imm, 1)
-        return default._replace(reg_row=regs[t].at[a].set(val), prng_t=sd)
+        return default._replace(reg_dst=dst, reg_val=val, prng_t=sd)
 
-    def _spin(proceed, addr):
+    def _spin(proceed):
         """Fused spin: proceed (load cost) or park camped on the line."""
-        ln = addr >> isa.LINE_SHIFT
         return default._replace(
-            cost=load_cost(ln),
-            new_pc=i32(jnp.where(proceed, pc1, pc[t])),
-            share_ln=ln,
+            cost=load_cost,
+            new_pc=i32(jnp.where(proceed, pc1, pc_t)),
+            share=yes,
             sleep=~proceed,
             park_addr=i32(jnp.where(proceed, -1, addr)))
 
     def h_spin_eq():
-        addr = rb + imm
-        return _spin(mem[addr] == ra, addr)
+        return _spin(m_val == ra)
 
     def h_spin_ne():
-        addr = rb + imm
-        return _spin(mem[addr] != ra, addr)
+        return _spin(m_val != ra)
 
     def h_spin_eqi():
-        addr = rb + imm
-        return _spin(mem[addr] == cc, addr)
+        return _spin(m_val == cc)
 
     def h_spin_nei():
-        addr = rb + imm
-        return _spin(mem[addr] != cc, addr)
+        return _spin(m_val != cc)
 
     def h_spin_ge():
         # Wrap-safe frontier compare: the sign of the int32 DIFFERENCE, not
@@ -459,18 +540,17 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         # once they cross INT32_MAX the grant is a huge negative while a
         # pre-wrap ticket frontier is a huge positive — `mem >= ra` would
         # park the waiter forever even though the frontier has passed it.
-        addr = rb + imm
-        return _spin(mem[addr] - ra >= 0, addr)
+        return _spin(m_val - ra >= 0)
 
     def h_acq():
         lidx = ra
-        rt = rel_time[lidx]
+        rt = _pick(rel_time, lock_ids == _clamped(lidx, lock_ids.shape[0]))
         waited = cc > 0
         got = waited & (rt >= 0)
         # acquire latency: a pending TSTART mark is consumed into the log2
         # histogram (marks survive aborted attempts until the next ACQ, so
         # redraw loops measure from the FIRST attempt)
-        t0v = acq_t0[t]
+        t0v = _pick(acq_t0, is_t)
         marked = t0v >= 0
         blat = jnp.maximum(now - t0v, 0)
         bucket = (blat >= (i32(1) << jnp.arange(N_LAT_BUCKETS - 1,
@@ -490,16 +570,14 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         return default._replace(rel_idx=rb, rel_val=now)
 
     def h_halt():
-        return default._replace(cost=i32(INF), new_pc=pc[t])
+        return default._replace(cost=i32(INF), new_pc=pc_t)
 
     def h_commit():
         """Pseudo-op: the earliest pending store becomes globally visible."""
-        addr = pend_addr[tc]
-        ln = addr >> isa.LINE_SHIFT
         return default._replace(
             advance=no, clear_pend=yes,
-            w_addr=addr, w_val=pend_val[tc],
-            excl_ln=ln, wake_addr=addr, wake_time=t_min)
+            w_addr=addr, w_val=_pick(pend_val, is_tc),
+            excl=yes, wake_addr=addr, wake_time=t_min)
 
     def h_noevent():
         """Pseudo-op for finished lanes: touch nothing."""
@@ -551,7 +629,8 @@ def _step(c: SimConsts, s: SimState) -> SimState:
 
     # ---- apply phase: every state update happens exactly once ------------
     actor = jnp.where(is_commit, tc, t)
-    adv = e.advance
+    is_actor = thr == actor
+    upd = is_actor & e.advance
 
     # wake watchers of the written line (commit / RMW); a woken thread pays
     # any preemption debt accrued while parked on top of C_WAKE
@@ -564,59 +643,45 @@ def _step(c: SimConsts, s: SimState) -> SimState:
         wd2 = wake_delay
     sp2 = jnp.where(wake, -1, spin_addr)
     # actor park / advance (the actor's own update wins over a wake)
-    sp2 = sp2.at[actor].set(jnp.where(e.park_addr >= 0, e.park_addr,
-                                      sp2[actor]))
-    nt2 = nt2.at[actor].set(jnp.where(
-        adv, jnp.where(e.sleep, INF, now + e.cost), nt2[actor]))
+    sp2 = jnp.where(is_actor & (e.park_addr >= 0), e.park_addr, sp2)
+    nt2 = jnp.where(upd, jnp.where(e.sleep, INF, now + e.cost), nt2)
 
-    pc2 = pc.at[actor].set(jnp.where(adv, e.new_pc, pc[actor]))
-    regs2 = regs.at[actor].set(jnp.where(adv, e.reg_row, regs[actor]))
-    prng2 = prng.at[actor].set(jnp.where(adv, e.prng_t, prng[actor]))
+    pc2 = jnp.where(upd, e.new_pc, pc)
+    regs2 = jnp.where(upd[:, None] & (reg_ids == e.reg_dst), e.reg_val, regs)
+    prng2 = jnp.where(upd, e.prng_t, prng)
 
     # immediate memory write (RMW / commit)
-    wa = jnp.where(e.w_addr >= 0, e.w_addr, 0)
-    mem2 = mem.at[wa].set(jnp.where(e.w_addr >= 0, e.w_val, mem[wa]))
+    mem2 = _write_at(mem, e.w_addr, e.w_val, dense)
 
-    # sharer registration (+ downgrade of a foreign dirty line): OR the
-    # actor's bit into its bitset word
-    a_bit = jnp.uint32(1) << (actor & 31).astype(jnp.uint32)
-    a_word = actor >> 5
-    ls = jnp.where(e.share_ln >= 0, e.share_ln, 0)
-    sh2 = sharers.at[ls, a_word].set(jnp.where(
-        e.share_ln >= 0, sharers[ls, a_word] | a_bit, sharers[ls, a_word]))
-    dr2 = dirty.at[ls].set(jnp.where((e.share_ln >= 0) & e.downgrade,
-                                     -1, dirty[ls]))
-    # exclusive ownership (RMW / commit): invalidate every other sharer —
-    # the whole row collapses to the actor's lone bit
-    n_words = sharers.shape[1]
-    le = jnp.where(e.excl_ln >= 0, e.excl_ln, 0)
-    lone = jnp.where(jnp.arange(n_words) == a_word, a_bit, jnp.uint32(0))
-    sh2 = sh2.at[le].set(jnp.where(e.excl_ln >= 0, lone, sh2[le]))
-    dr2 = dr2.at[le].set(jnp.where(e.excl_ln >= 0, actor, dr2[le]))
+    # The one line an event touches is the fetched ``ln``: a load or spin
+    # registers the actor as a sharer (OR its bit into ``ln_row``; a foreign
+    # dirty owner is downgraded), an RMW or commit takes it exclusive (the
+    # row collapses to the actor's lone bit).  No event does both.
+    a_row = bit_row(actor)
+    w_ln = jnp.where(e.excl | e.share, ln, -1)
+    sh2 = _write_at(sharers, w_ln, jnp.where(e.excl, a_row, ln_row | a_row),
+                    dense)
+    dr2 = _write_at(dirty, w_ln,
+                    jnp.where(e.excl, actor, jnp.where(e.downgrade, -1, d)),
+                    dense)
 
     # pending-store queue (enqueue on STORE/STOREI, consume on commit)
-    pa2 = pend_addr.at[actor].set(jnp.where(
-        e.st_addr >= 0, e.st_addr,
-        jnp.where(e.clear_pend, -1, pend_addr[actor])))
-    pv2 = pend_val.at[actor].set(jnp.where(e.st_addr >= 0, e.st_val,
-                                           pend_val[actor]))
-    pt2 = pend_time.at[actor].set(jnp.where(e.st_addr >= 0, e.st_time,
-                                            pend_time[actor]))
+    enq = is_actor & (e.st_addr >= 0)
+    pa2 = jnp.where(enq, e.st_addr,
+                    jnp.where(is_actor & e.clear_pend, -1, pend_addr))
+    pv2 = jnp.where(enq, e.st_val, pend_val)
+    pt2 = jnp.where(enq, e.st_time, pend_time)
 
     # lock bookkeeping
-    acq2 = acq.at[actor].add(e.acq_inc.astype(jnp.int32))
-    wacq2 = waited_acq.at[actor].add(e.waited_inc.astype(jnp.int32))
-    ri = jnp.where(e.rel_idx >= 0, e.rel_idx, 0)
-    rel2 = rel_time.at[ri].set(jnp.where(e.rel_idx >= 0, e.rel_val,
-                                         rel_time[ri]))
+    acq2 = acq + (is_actor & e.acq_inc).astype(jnp.int32)
+    wacq2 = waited_acq + (is_actor & e.waited_inc).astype(jnp.int32)
+    rel2 = jnp.where(lock_ids == e.rel_idx, e.rel_val, rel_time)
     hs2 = hand_sum + e.hand_add
     hc2 = hand_cnt + e.hand_inc.astype(jnp.int32)
 
     # acquire-latency mark + log2 histogram
-    t02 = acq_t0.at[actor].set(jnp.where(e.t0_new != -2, e.t0_new,
-                                         acq_t0[actor]))
-    li = jnp.where(e.lat_idx >= 0, e.lat_idx, 0)
-    lh2 = lat_hist.at[li].add((e.lat_idx >= 0).astype(jnp.int32))
+    t02 = jnp.where(is_actor & (e.t0_new != -2), e.t0_new, acq_t0)
+    lh2 = lat_hist + (jnp.arange(N_LAT_BUCKETS) == e.lat_idx).astype(jnp.int32)
 
     return SimState(nt2, pc2, regs2, prng2, mem2, sh2, dr2,
                     pa2, pv2, pt2, sp2, wd2,
@@ -785,7 +850,7 @@ def _make_run_map(n_threads: int, mem_words: int, n_locks: int):
     driver, but cells execute sequentially inside the compiled program.  On
     CPU this wins: a lane-parallel sweep costs ``max(events) × B`` lane-steps
     (idle lanes still pay the switch) while the sequential map costs
-    ``sum(events)`` — and scalar XLA scatters see no SIMD benefit anyway.
+    ``sum(events)`` — and a scalar step sees no SIMD benefit anyway.
     ``loop_iters`` holds each cell's own loop count, shape ``(B,)``.
     """
     run = _make_run(n_threads, mem_words, n_locks)
@@ -1073,8 +1138,8 @@ def _broadcast_cells(x, n_cells: int, dtype) -> np.ndarray:
 
 
 # Scheduler defaults, tuned on CPU: few lanes (the per-step cost of the
-# scalar scatter/gather step scales with lane count there) and bursts long
-# enough to amortize the refill check's gather/select over the lane state.
+# step scales with lane count there) and bursts long enough to amortize the
+# refill check's gather/select over the lane state.
 DEFAULT_LANES = 4
 DEFAULT_CHUNK = 512
 
@@ -1295,7 +1360,7 @@ def _pad_stats(programs: np.ndarray, n_active: np.ndarray, n_threads: int,
 
     Batched cells are padded to shared shapes, and the padding is pure
     overhead the drivers carry: inactive threads still occupy rows in every
-    per-thread gather/scatter, padded program rows occupy the instruction
+    per-thread mask, padded program rows occupy the instruction
     table, padded memory words occupy hot state (and sharer-bitset lines).
     ``bench_engine`` and fuzz runs report these fractions so packer
     regressions are visible instead of silently eaten as wall-clock.

@@ -5,9 +5,10 @@ must cost a single engine compilation."""
 import numpy as np
 import pytest
 
-from repro.sim import (SweepSpec, pad_program, pad_threads, run_contention,
-                       run_sweep)
+from repro.sim import (SIM_LOCKS, SweepSpec, pad_program, pad_threads,
+                       run_contention, run_sweep)
 from repro.sim.engine import engine_cache_info, run_sim
+from repro.sim.faults import FaultSchedule
 from repro.sim.programs import (INIT_MEM_GEN, Layout, build_mutexbench,
                                 init_state)
 
@@ -78,17 +79,69 @@ def test_sweep_single_compile_across_thread_counts():
     assert again.misses == after.misses
 
 
-def test_sweep_modes_bitwise_equal():
-    """The lane-parallel (vmap) and sequential (map) sweep drivers must
-    produce identical results."""
-    spec = SweepSpec(locks=("ticket", "twa"), threads=(2, 4), seeds=1,
-                     horizon=60_000)
-    res_map = run_sweep(spec, mode="map")
-    res_vmap = run_sweep(spec, mode="vmap")
-    for a, b in zip(res_map, res_vmap):
-        assert np.array_equal(a["acquisitions"], b["acquisitions"])
-        assert a["events"] == b["events"]
-        assert np.array_equal(a["mem"], b["mem"])
+# MutexBench's 13 locks (Figure 3): every single-lock algorithm that holds
+# 64 threads; twa-timo's 32-slot abandonment ring cannot.
+MUTEXBENCH_LOCKS = tuple(lk for lk in SIM_LOCKS if lk != "twa-timo")
+# the drivers compared against mode="map", as (mode, lanes)
+DRIVERS = (("vmap", None), ("sched", 1), ("sched", 3), ("sched", 4))
+# result keys that describe the driver, not the cell
+DRIVER_KEYS = ("mode", "pad_stats")
+
+
+# The sweeps compared, as (locks, threads, horizon, faults): every
+# MutexBench lock at 3 and 33 threads (two bitset words), without and with
+# faults (preemptions, a spurious wake and an abort per cell); and ticket
+# and twa at 2 and 4 threads over a long horizon.
+GEOMETRIES = {
+    "fault-free": (MUTEXBENCH_LOCKS, (3, 33), 8_000, False),
+    "faults": (MUTEXBENCH_LOCKS, (3, 33), 8_000, True),
+    "long": (("ticket", "twa"), (2, 4), 60_000, False),
+}
+
+
+@pytest.fixture(scope="module")
+def mode_sweeps(request) -> dict:
+    """One sweep of a geometry, latency histograms on, run by each
+    driver."""
+    locks, threads, horizon, faults = GEOMETRIES[request.param]
+    fault_axes = (dict(preempt_faults=2, spurious_faults=1, abort_faults=1,
+                       preempt_cost=300, fault_evt_span=400)
+                  if faults else {})
+    spec = SweepSpec(locks=locks, threads=threads, seeds=1, horizon=horizon,
+                     collect_latency=True, **fault_axes)
+    runs = {("map", None): run_sweep(spec, mode="map")}
+    for mode, lanes in DRIVERS:
+        runs[mode, lanes] = run_sweep(spec, mode=mode, lanes=lanes)
+    return runs
+
+
+def _same(a, b) -> bool:
+    if isinstance(a, FaultSchedule):
+        return a.to_lists() == b.to_lists()
+    if isinstance(a, float) and np.isnan(a):
+        return np.isnan(b)
+    return np.array_equal(a, b)
+
+
+@pytest.mark.parametrize(
+    "mode_sweeps,lock",
+    [(name, lock) for name, (locks, *_) in GEOMETRIES.items()
+     for lock in locks],
+    indirect=["mode_sweeps"])
+def test_sweep_modes_bitwise_equal(lock, mode_sweeps):
+    """The lane-parallel (vmap) and work-stealing (sched, 1, 3 and 4 lanes)
+    drivers must reproduce the sequential (map) driver's every result key,
+    cell by cell."""
+    ref = mode_sweeps["map", None]
+    rows = [i for i, r in enumerate(ref) if r["lock"] == lock]
+    assert len(rows) == 2
+    for driver in DRIVERS:
+        for i in rows:
+            got, want = mode_sweeps[driver][i], ref[i]
+            assert got.keys() == want.keys()
+            for key in want.keys() - set(DRIVER_KEYS):
+                assert _same(got[key], want[key]), \
+                    (driver, lock, want["n_threads"], key)
 
 
 def test_sweep_cells_cartesian_order():
