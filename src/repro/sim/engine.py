@@ -242,6 +242,15 @@ def _pick(x, hit):
 DENSE_MEM_WORDS = 1 << 15
 
 
+def memory_form(mem_words: int, backend: str) -> str:
+    """How :func:`_step` reads and writes memory and its lines in a sweep of
+    ``mem_words`` words on ``backend``: ``"mask"`` on a TPU up to
+    :data:`DENSE_MEM_WORDS` words, ``"index"`` past that and elsewhere."""
+    if backend == "tpu" and mem_words <= DENSE_MEM_WORDS:
+        return "mask"
+    return "index"
+
+
 def _read_at(x, i, dense: bool):
     """``x[i]`` along axis 0, by mask or by index."""
     n = x.shape[0]
@@ -270,7 +279,8 @@ def _step(c: SimConsts, s: SimState) -> SimState:
     :data:`DENSE_MEM_WORDS` words, and by one index per array otherwise;
     both forms give the same state bit for bit.
     """
-    if s.mem.shape[0] > DENSE_MEM_WORDS:
+    # past the dense size even a TPU lowering takes the index form
+    if memory_form(s.mem.shape[0], "tpu") == "index":
         return _step_in(c, s, dense=False)
     return jax.lax.platform_dependent(
         c, s, tpu=functools.partial(_step_in, dense=True),
@@ -1244,9 +1254,10 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
 
     Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``):
     ``lockvm.dispatch`` around the upload and the jitted call, its
-    arguments the compile key; ``lockvm.readback`` around the copy of the
-    outputs to the host; ``lockvm.assemble`` around the bookkeeping, with
-    ``lanes`` and ``lane_steps`` as arguments.
+    arguments the compile key and the memory form the step takes there
+    (``mem_form``, :func:`memory_form`); ``lockvm.readback`` around the
+    copy of the outputs to the host; ``lockvm.assemble`` around the
+    bookkeeping, with ``lanes`` and ``lane_steps`` as arguments.
     """
     programs = np.asarray(programs, np.int32)
     assert programs.ndim == 3 and programs.shape[2] == 5, programs.shape
@@ -1317,7 +1328,9 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
     with TraceAnnotation("lockvm.dispatch", mode=mode, cells=n_cells,
                          n_threads=n_threads, mem_words=mem_words,
                          prog_len=prog_len, lanes=lanes, chunk=chunk,
-                         n_faults=n_faults):
+                         n_faults=n_faults, n_locks=n_locks,
+                         mem_form=memory_form(mem_words,
+                                              jax.default_backend())):
         engine = _build_engine(n_threads, mem_words, n_locks, prog_len,
                                batched=mode, n_lanes=lanes, chunk=chunk,
                                interpret=interpret, n_faults=n_faults)

@@ -496,10 +496,16 @@ def fig2_interlock_interference(pool_sizes=(1, 4, 16, 64, 256, 1024),
                                 horizon: int = 600_000) -> list[float]:
     """Fig 2: shared-array TWA throughput / private-array TWA throughput.
 
-    The paper sweeps 1..8192 locks on real hardware; we sweep to 1024 (memory
-    for per-lock private arrays bounds the idealized variant).  <1.0 means
-    inter-lock collisions/false-sharing cost; paper's worst case is ~8%.
-    Each pool size is one sweep over the (private_arrays × seeds) axes.
+    64 threads each pick a random lock from a pool of ``n_locks`` every
+    iteration.  The paper sweeps pools of 1 to 8 192 locks on real hardware
+    and reports a worst penalty of the shared array under 8%.  By default
+    this sweeps 1 to 1 024 (the idealized private arrays grow by ``wa_size``
+    words a lock); ``benchmarks/fig2_interlock_interference.py`` and the
+    chip benchmark's ``interlock`` configuration run 1, 8 and 64.  Below
+    1.0 is the cost of inter-lock collisions and false sharing in the one
+    array.  CS 50 and NCS U[0,100) PRNG steps are this repository's own
+    settings, not the paper's.  Each pool size is one sweep over the
+    (private_arrays × seeds) axes.
     """
     ratios = []
     for n_locks in pool_sizes:
