@@ -33,9 +33,11 @@ STAT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
 # Scheduler-geometry pool for fuzz batches.  The differential must exercise
 # the lane scheduler itself, not just the default 4×512 point: chunk=1
 # (refill check after every single step), a lone lane, lane counts above
-# typical sub-batch sizes (the B < lanes clamp), and the CPU default.
+# typical sub-batch sizes (the B < lanes clamp), the host default, and the
+# TPU's width.
 SCHED_GEOMETRY_POOL = ((1, 1), (2, 64), (3, 1), (6, 128),
-                       (engine.DEFAULT_LANES, engine.DEFAULT_CHUNK))
+                       (engine.DEFAULT_LANES, engine.DEFAULT_CHUNK),
+                       (engine.TPU_LANES, engine.TPU_CHUNK))
 
 # Burst-chunk pool for the pallas driver.  chunk=1 terminates the in-kernel
 # while_loop after every single step (no overshoot), 16 overshoots on

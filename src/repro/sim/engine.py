@@ -55,12 +55,13 @@ Structure (batched-sweep refactor):
     (sequential cells), ``mode="sched"`` — a chunked work-stealing lane
     scheduler (:func:`_make_run_sched`): ``lanes`` lanes step in fixed-size
     chunks inside an outer while loop, and a lane whose cell finished is
-    refilled from the queue of not-yet-started cells.  A skewed sweep then
-    costs ~``sum(events)`` lane-steps instead of vmap's ``max(events) × B``,
-    while per-cell results stay bit-identical to ``mode="map"`` (each cell
-    still executes its private event sequence — only lane placement
-    changes).  ``mode="pallas"`` (:mod:`repro.sim.engine_pallas`) fuses the
-    whole per-cell event loop into one Pallas kernel grid step — hot state
+    refilled from the queue of not-yet-started cells, longest first.  A
+    skewed sweep then costs ~``sum(events)`` lane-steps instead of vmap's
+    ``max(events) × B``, while per-cell results stay bit-identical to
+    ``mode="map"`` (each cell still executes its private event sequence —
+    only lane placement changes).  ``mode="pallas"``
+    (:mod:`repro.sim.engine_pallas`) fuses the whole per-cell event loop
+    into one Pallas kernel grid step — hot state
     resident in kernel memory across a ``chunk``-event burst instead of a
     per-event ``lax.while_loop`` carry; it runs in interpret mode on CPU and
     does not lower for TPU yet (see :data:`repro.sim.engine_pallas.
@@ -885,6 +886,10 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
     event sequence via the self-guarding step, so per-cell results are
     bit-identical to ``mode="map"`` — only lane placement changes.
 
+    The queue hands out cells in the order of the ``(B,)`` input ``order``:
+    slot ``k`` holds cell ``order[k]``.  ``lane_cell`` keeps the cell's own
+    index, so outputs land in submission slots whatever the order.
+
     A lane whose queue ran dry parks with ``lane_cell = -1`` and a zero
     horizon, making its steps free no-events until the loop ends.
     ``loop_iters`` counts the outer bursts, each ``chunk`` steps of every lane.
@@ -892,7 +897,7 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
 
     def lockvm_sched(program, init_pc, init_regs, init_mem, n_active, seed,
                      horizon, max_events, costs, wa_base, wa_mask, wa_size,
-                     *faults):
+                     order, *faults):
         n_cells = program.shape[0]
         lanes = min(n_lanes, n_cells)
 
@@ -914,11 +919,11 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
         vstep = jax.vmap(_step)
 
         def cond(carry):
-            lane_cell, next_cell, _, _, _ = carry
-            return (next_cell < n_cells) | jnp.any(lane_cell >= 0)
+            lane_cell, next_slot, _, _, _ = carry
+            return (next_slot < n_cells) | jnp.any(lane_cell >= 0)
 
         def body(carry):
-            lane_cell, next_cell, s, outs, bursts = carry
+            lane_cell, next_slot, s, outs, bursts = carry
             c = lane_consts(lane_cell)
             s = jax.lax.fori_loop(0, chunk, lambda _, st: vstep(c, st), s)
             # terminated lanes: exact negation of the step's ``live`` guard,
@@ -952,20 +957,21 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
                     outs["lat_hist"].at[idx].set(s.lat_hist, mode="drop"),
             }
             # work stealing: the i-th finished lane (in lane order) claims
-            # queue slot next_cell + i; lanes past the queue end park
+            # queue slot next_slot + i; lanes past the queue end park
             rank = jnp.cumsum(fin.astype(jnp.int32)) - fin.astype(jnp.int32)
-            cand = next_cell + rank
-            gets = fin & (cand < n_cells)
-            lane_cell = jnp.where(fin, jnp.where(gets, cand, -1), lane_cell)
-            next_cell = jnp.minimum(next_cell + fin.sum(), n_cells)
+            slot = next_slot + rank
+            gets = fin & (slot < n_cells)
+            queued = order[jnp.clip(slot, 0, n_cells - 1)]
+            lane_cell = jnp.where(fin, jnp.where(gets, queued, -1), lane_cell)
+            next_slot = jnp.minimum(next_slot + fin.sum(), n_cells)
             fresh = jax.vmap(cell_init)(jnp.clip(lane_cell, 0, n_cells - 1))
             s = jax.tree_util.tree_map(
                 lambda new, old: jnp.where(
                     gets.reshape((lanes,) + (1,) * (old.ndim - 1)), new, old),
                 fresh, s)
-            return lane_cell, next_cell, s, outs, bursts + 1
+            return lane_cell, next_slot, s, outs, bursts + 1
 
-        lane_cell0 = jnp.arange(lanes, dtype=jnp.int32)
+        lane_cell0 = order[:lanes]
         outs0 = {
             "acquisitions": jnp.zeros((n_cells, n_threads), jnp.int32),
             "waited_acquisitions": jnp.zeros((n_cells, n_threads), jnp.int32),
@@ -1147,17 +1153,51 @@ def _broadcast_cells(x, n_cells: int, dtype) -> np.ndarray:
     return arr
 
 
-# Scheduler defaults, tuned on CPU: few lanes (the per-step cost of the
-# step scales with lane count there) and bursts long enough to amortize the
+# Scheduler geometry off a TPU: few lanes (the step's cost grows with the
+# lane count on a host backend) and bursts long enough to amortize the
 # refill check's gather/select over the lane state.
 DEFAULT_LANES = 4
 DEFAULT_CHUNK = 512
+# On a TPU a step's fixed cost dominates (48 lanes cost about 1.6 times 4),
+# so a sweep runs as many lanes as it has cells, up to TPU_LANES; wider, the
+# last wave of cells leaves too many lanes idle.  Both numbers are the best
+# of a table measured on one TPU v5e over the mutexbench and locktorture
+# grid sweeps: lanes 8-64 x chunk 128-512 x queue order (PERF.md section 5).
+TPU_LANES = 48
+TPU_CHUNK = 256
 
 # mode="auto" thresholds: a sweep is "skewed" when its heaviest cell carries
 # at least twice the mean estimated work and there are enough cells for the
 # work-stealing scheduler to amortize its refill machinery.
 AUTO_SKEW_RATIO = 2.0
 AUTO_SKEW_MIN_CELLS = 8
+
+
+def sched_geometry(backend: str, n_cells: int) -> tuple[int, int]:
+    """The ``(lanes, chunk)`` the ``"sched"`` driver runs a sweep of
+    ``n_cells`` cells at on ``backend`` where the caller names none.  It
+    depends on shapes alone, so a warm-up at another horizon compiles the
+    executable the real sweep runs."""
+    if backend == "tpu":
+        return min(n_cells, TPU_LANES), TPU_CHUNK
+    return DEFAULT_LANES, DEFAULT_CHUNK
+
+
+def _work_estimate(n_cells: int, horizon, n_active) -> np.ndarray:
+    """Each cell's estimated work, ``horizon × n_active`` (int64): the event
+    count is horizon-bound for live cells and padded threads never run."""
+    horizon = np.broadcast_to(np.asarray(horizon, np.int64), (n_cells,))
+    n_active = np.broadcast_to(np.asarray(n_active, np.int64), (n_cells,))
+    return horizon * n_active
+
+
+def queue_order(n_cells: int, horizon, n_active) -> np.ndarray:
+    """The ``"sched"`` driver's queue: cell indices, longest estimated work
+    first (:func:`_work_estimate`), ties in submission order, as int32.  The
+    last cells to start are then the short ones, so no long cell runs alone
+    at the end of a sweep."""
+    est = _work_estimate(n_cells, horizon, n_active)
+    return np.argsort(-est, kind="stable").astype(np.int32)
 
 
 def choose_mode(backend: str, *, n_cells: int, n_threads: int,
@@ -1177,14 +1217,10 @@ def choose_mode(backend: str, *, n_cells: int, n_threads: int,
 
     ``"pallas"`` is never picked: Mosaic refuses its kernel
     (:data:`repro.sim.engine_pallas.TPU_REFUSAL`).  Work per cell is
-    estimated as ``horizon × n_active`` — the event count is horizon-bound
-    for live cells and padded threads never run.
+    estimated as ``horizon × n_active`` (:func:`_work_estimate`).
     """
-    horizon = np.broadcast_to(np.asarray(horizon, np.int64), (n_cells,))
-    if n_active is None:
-        n_active = n_threads
-    n_active = np.broadcast_to(np.asarray(n_active, np.int64), (n_cells,))
-    est = horizon * n_active
+    est = _work_estimate(n_cells, horizon,
+                         n_threads if n_active is None else n_active)
     skewed = (n_cells >= AUTO_SKEW_MIN_CELLS
               and est.max() * n_cells >= AUTO_SKEW_RATIO * est.sum())
     if skewed:
@@ -1233,6 +1269,8 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
       lanes/chunk: driver geometry — ``lanes`` ("sched" only) is the number
         of parallel work-stealing lanes (clamped to B); ``chunk`` ("sched"
         and "pallas") is the steps per burst between termination checks.
+        Under "sched" either left None comes from :func:`sched_geometry`;
+        the queue hands out cells longest first (:func:`queue_order`).
       interpret: "pallas" only — force the Pallas interpreter on/off; None
         autodetects (interpret unless the backend is a TPU).
       live_mem_words: optional (B,) per-cell *unpadded* memory sizes, used
@@ -1254,10 +1292,12 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
 
     Host spans on the profiler's clock (``jax.profiler.TraceAnnotation``):
     ``lockvm.dispatch`` around the upload and the jitted call, its
-    arguments the compile key and the memory form the step takes there
-    (``mem_form``, :func:`memory_form`); ``lockvm.readback`` around the
-    copy of the outputs to the host; ``lockvm.assemble`` around the
-    bookkeeping, with ``lanes`` and ``lane_steps`` as arguments.
+    arguments the compile key, the memory form the step takes there
+    (``mem_form``, :func:`memory_form`) and the order of the ``"sched"``
+    queue (``queue``: ``"longest_first"``, ``"none"`` under the other
+    drivers); ``lockvm.readback`` around the copy of the outputs to the
+    host; ``lockvm.assemble`` around the bookkeeping, with ``lanes`` and
+    ``lane_steps`` as arguments.
     """
     programs = np.asarray(programs, np.int32)
     assert programs.ndim == 3 and programs.shape[2] == 5, programs.shape
@@ -1277,8 +1317,9 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
                  n_threads, mem_words)
     assert mode in ("vmap", "map", "sched", "pallas"), mode
     if mode == "sched":
-        lanes = DEFAULT_LANES if lanes is None else lanes
-        chunk = DEFAULT_CHUNK if chunk is None else chunk
+        geometry = sched_geometry(jax.default_backend(), n_cells)
+        lanes = geometry[0] if lanes is None else lanes
+        chunk = geometry[1] if chunk is None else chunk
         assert lanes >= 1 and chunk >= 1, (lanes, chunk)
     elif mode == "pallas":
         from ..kernels import resolve_interpret
@@ -1323,6 +1364,8 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         fault_args, n_faults = (), 0
 
     n_active_arr = _broadcast_cells(n_active, n_cells, np.int32)
+    queue = ((queue_order(n_cells, horizon, n_active_arr),)
+             if mode == "sched" else ())
     # the span's arguments are the compile key, so a recompile shows nested
     # under the span that names it
     with TraceAnnotation("lockvm.dispatch", mode=mode, cells=n_cells,
@@ -1330,7 +1373,8 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
                          prog_len=prog_len, lanes=lanes, chunk=chunk,
                          n_faults=n_faults, n_locks=n_locks,
                          mem_form=memory_form(mem_words,
-                                              jax.default_backend())):
+                                              jax.default_backend()),
+                         queue="longest_first" if queue else "none"):
         engine = _build_engine(n_threads, mem_words, n_locks, prog_len,
                                batched=mode, n_lanes=lanes, chunk=chunk,
                                interpret=interpret, n_faults=n_faults)
@@ -1345,7 +1389,7 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
             jnp.asarray(_broadcast_cells(wa_base, n_cells, np.int32)),
             jnp.asarray(wa_size_arr - 1),
             jnp.asarray(wa_size_arr),
-            *(jnp.asarray(a) for a in fault_args))
+            *(jnp.asarray(a) for a in queue + fault_args))
     with TraceAnnotation("lockvm.readback"):
         res = {k: np.asarray(v) for k, v in out.items()}
     loop_iters = res.pop("loop_iters")

@@ -71,22 +71,23 @@ def _fig3_shape():
             max(spec.layout_for(c).mem_words for c in cells))
 
 
-def _sweep_args(sharding, n_cells, n_threads, mem_words):
+def _sweep_args(sharding, n_cells, n_threads, mem_words, mode):
     s = functools.partial(_sds, sharding)
+    queue = (s((n_cells,)),) if mode == "sched" else ()   # the queue's order
     return (s((n_cells, PROG_LEN, 5)), s((n_cells, n_threads)),
             s((n_cells, n_threads, isa.N_REGS)), s((n_cells, mem_words)),
             s((n_cells,)), s((n_cells,), jnp.uint32), s((n_cells,)),
             s((n_cells,)), s((n_cells, 9)), s((n_cells,)), s((n_cells,)),
-            s((n_cells,)))
+            s((n_cells,))) + queue
 
 
-def _drivers(n_threads, mem_words):
+def _drivers(n_cells, n_threads, mem_words):
     return {
         "map": lambda: engine._make_run_map(n_threads, mem_words, 1),
         "vmap": lambda: engine._make_run_batched(n_threads, mem_words, 1),
+        # the geometry a TPU resolves for this sweep
         "sched": lambda: engine._make_run_sched(
-            n_threads, mem_words, 1, engine.DEFAULT_LANES,
-            engine.DEFAULT_CHUNK),
+            n_threads, mem_words, 1, *engine.sched_geometry("tpu", n_cells)),
         "pallas": lambda: make_run_pallas(n_threads, mem_words, 1, PROG_LEN,
                                           128, interpret=False),
     }
@@ -103,9 +104,9 @@ def _drivers(n_threads, mem_words):
 def test_sweep_driver_compiles_at_fig3_shape(one_chip, mode):
     n_cells, n_threads, mem_words = _fig3_shape()
     assert (n_cells, n_threads) == (273, 64)
-    fn = _drivers(n_threads, mem_words)[mode]()
+    fn = _drivers(n_cells, n_threads, mem_words)[mode]()
     compiled = jax.jit(fn).lower(
-        *_sweep_args(one_chip, n_cells, n_threads, mem_words)).compile()
+        *_sweep_args(one_chip, n_cells, n_threads, mem_words, mode)).compile()
     assert compiled.memory_analysis().argument_size_in_bytes > 0
 
 

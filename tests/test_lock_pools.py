@@ -163,3 +163,4 @@ def test_the_dispatch_span_names_the_pool_and_the_memory_form(tmp_path):
     assert args["mem_form"] == engine.memory_form(mem_words,
                                                   jax.default_backend())
     assert args["mem_form"] == "index"                  # on the CPU
+    assert args["queue"] == "none"                      # no queue in vmap

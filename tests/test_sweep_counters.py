@@ -154,6 +154,7 @@ def test_a_traced_sweep_marks_its_host_phases_in_order(tmp_path, monkeypatch):
     assert inner[2][3] == {"mode": "sched", "cells": 8, "n_threads": 3,
                            "mem_words": mem_words, "prog_len": PROG_LEN,
                            "lanes": 2, "chunk": 64, "n_faults": 0,
-                           "n_locks": 1, "mem_form": "index"}
+                           "n_locks": 1, "mem_form": "index",
+                           "queue": "longest_first"}
     ps = rows[0]["pad_stats"]
     assert inner[4][3] == {"lanes": 2, "lane_steps": ps["lane_steps"]}
