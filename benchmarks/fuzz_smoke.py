@@ -3,8 +3,8 @@
 Three stages:
 
   1. **Differential smoke** — a small deterministic batch (composed lock
-     scenarios + random ISA programs) through the oracle and all four
-     engine sweep modes, asserting zero differential/invariant failures,
+     scenarios + random ISA programs) through the oracle and the three
+     engine sweep drivers, asserting zero differential/invariant failures,
      then mutation self-tests (``eager_store``, through BOTH the
      sequential and the batch oracle path) proving the checker still
      catches what it claims to catch.
@@ -35,7 +35,7 @@ import time
 
 import numpy as np
 
-from repro.sim.check import (fuzz, generate_batch, load_scenario,
+from repro.sim.check import (MODES, fuzz, generate_batch, load_scenario,
                              run_batch_oracle, run_oracle_case)
 from repro.sim.check import _fastcase
 from repro.sim.check.runner import STAT_KEYS
@@ -89,12 +89,12 @@ def run(smoke: bool = False, json_path: str | None = None) -> dict:
     n_cases = SMOKE_CASES if smoke else CASES
     scenarios = generate_batch(n_cases, SEED)
     t0 = time.time()
-    # oracle vs map/vmap/sched/pallas (randomized lane geometry and
-    # pallas burst chunk) + invariants
+    # oracle vs map/vmap/sched (randomized sched lane geometry) +
+    # invariants
     report = fuzz(scenarios, sched_seed=SEED)
     dt = time.time() - t0
     emit("fuzz/cases", n_cases,
-         f"composed+random, seed={SEED}, modes=map/vmap/sched/pallas")
+         f"composed+random, seed={SEED}, modes={'/'.join(MODES)}")
     emit("fuzz/oracle_events", report.total_events,
          f"{report.total_events / max(dt, 1e-9):,.0f} events/s")
     emit("fuzz/failures", len(report.failures),

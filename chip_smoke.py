@@ -89,7 +89,6 @@ def phase_drivers(cells=None) -> dict:
     """(c) ``map``/``vmap``/``sched`` agree bit for bit on one sweep."""
     from benchmarks.bench_engine import SMOKE_CELLS
     from repro.sim import engine
-    from repro.sim.engine_pallas import OUT_KEYS
     from repro.sim.workloads import pack_engine_cells
     programs, kw = pack_engine_cells(cells or SMOKE_CELLS, seeds=1,
                                      collect_latency=True)
@@ -97,7 +96,7 @@ def phase_drivers(cells=None) -> dict:
     for mode in CHIP_DRIVERS:
         out = engine.run_sweep(programs, mode=mode, **kw)
         ref = out if ref is None else ref
-        for key in OUT_KEYS:
+        for key in engine.OUT_KEYS:
             assert np.array_equal(ref[key], out[key]), (mode, key)
     log(f"drivers: {'/'.join(CHIP_DRIVERS)} bit-identical on "
         f"{len(programs)} cells, sum_events={int(ref['events'].sum())}")

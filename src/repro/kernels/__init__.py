@@ -6,9 +6,8 @@ select kernel vs pure-jnp oracle via use_pallas).
 * mamba_scan     — Mamba-1 selective scan (falcon-mamba hot spot).
 * rglru          — RG-LRU gated linear recurrence (recurrentgemma hot spot).
 
-All kernels (and the lockVM's ``mode="pallas"`` sweep driver in
-``repro.sim.engine_pallas``) share :func:`default_interpret` to decide
-whether ``pallas_call`` should compile natively or run the interpreter:
+All kernels share :func:`default_interpret` to decide whether
+``pallas_call`` should compile natively or run the interpreter:
 natively exactly on a TPU, the one accelerator this repo targets.  Every
 entry point keeps ``interpret`` overridable (and jit-static), so tests can
 force the interpreter on a device and device runs can be forced from

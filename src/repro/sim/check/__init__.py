@@ -16,9 +16,8 @@ Layers:
     histograms, lock x invariant-class, wrap/collision events) accumulated
     into a run-level :class:`~repro.sim.check.coverage.CoverageMap`.
   * :mod:`invariants` + :mod:`runner` — oracle vs ``run_sweep`` differential
-    execution (bit-identical stats across
-    ``mode="map"/"vmap"/"sched"/"pallas"``, with per-case randomized sched
-    lane geometry and pallas burst chunk), engine-independent
+    execution (bit-identical stats across ``mode="map"/"vmap"/"sched"``,
+    with per-case randomized sched lane geometry), engine-independent
     invariants (exclusion incl. the weighted rw probe, wrap-aware
     conservation/FIFO, per-thread liveness bounds, deadlock, collision),
     a greedy shrinker, coverage-guided steering, batched corpus replay,
@@ -36,12 +35,12 @@ from .generate import (PAD_LOCKS, PAD_MEM_WORDS, PAD_THREADS, Scenario,
                        scenario_faults, splice_programs, with_fault_schedule)
 from .invariants import active_classes, check_invariants
 from .oracle import ORACLE_MUTATIONS, Trace, run_oracle
-from .runner import (MODES, PALLAS_CHUNK_POOL, SCHED_GEOMETRY_POOL,
-                     FuzzReport, SteerResult, case_fails, case_problems,
-                     check_case, count_instructions, failure_classes, fuzz,
-                     load_scenario, pallas_chunks, replay_corpus,
-                     run_engine_batch, run_oracle_case, save_scenario,
-                     sched_geometries, shrink, steer)
+from .runner import (MODES, SCHED_GEOMETRY_POOL, FuzzReport, SteerResult,
+                     case_fails, case_problems, check_case,
+                     count_instructions, failure_classes, fuzz,
+                     load_scenario, replay_corpus, run_engine_batch,
+                     run_oracle_case, save_scenario, sched_geometries,
+                     shrink, steer)
 
 __all__ = [
     "Scenario", "gen_geometry", "gen_random_scenario",
@@ -57,5 +56,4 @@ __all__ = [
     "count_instructions", "run_engine_batch", "run_oracle_case",
     "save_scenario", "load_scenario", "MODES",
     "sched_geometries", "SCHED_GEOMETRY_POOL",
-    "pallas_chunks", "PALLAS_CHUNK_POOL",
 ]

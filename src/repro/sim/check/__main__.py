@@ -3,10 +3,10 @@
     PYTHONPATH=src python -m repro.sim.check --cases 200 --seed from-run-id
 
 Runs a mixed batch (composed lock scenarios + random ISA programs) through
-the oracle and all four engine sweep modes (``pallas`` in interpret mode on
-CPU), checks the invariant catalog,
-and on failure greedily shrinks the first failing case and writes it as a
-replayable ``.npz`` under ``--artifact-dir`` before exiting nonzero.
+the oracle and the engine's sweep drivers (``map``, ``vmap``, ``sched``),
+checks the invariant catalog, and on failure greedily shrinks the first
+failing case and writes it as a replayable ``.npz`` under
+``--artifact-dir`` before exiting nonzero.
 
 ``--seed from-run-id`` derives the seed from ``$GITHUB_RUN_ID`` (falling
 back to 0), so every CI run explores a fresh region while staying exactly
@@ -66,6 +66,17 @@ def _resolve_seed(spec: str) -> int:
     return int(spec)
 
 
+def _modes(spec: str) -> tuple:
+    """``--modes``: comma-separated names, each one of :data:`MODES`."""
+    modes = tuple(m for m in spec.split(",") if m)
+    unknown = [m for m in modes if m not in MODES]
+    if unknown:
+        raise argparse.ArgumentTypeError(
+            f"unknown mode(s) {', '.join(unknown)}; choose from "
+            f"{', '.join(MODES)}")
+    return modes
+
+
 def _replay(args, modes, mutate) -> int:
     """Replay a corpus entry (file) or a whole corpus (directory)."""
     if os.path.isdir(args.replay):
@@ -120,7 +131,7 @@ def main(argv=None) -> int:
     ap.add_argument("--seed", default="0",
                     help="int, or 'from-run-id' to derive from "
                          "$GITHUB_RUN_ID")
-    ap.add_argument("--modes", default=",".join(MODES),
+    ap.add_argument("--modes", type=_modes, default=MODES,
                     help="comma-separated engine sweep modes to diff")
     ap.add_argument("--artifact-dir", default="",
                     help="where to write the shrunk failing case (.npz)")
@@ -160,7 +171,7 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
 
     seed = _resolve_seed(args.seed)
-    modes = tuple(m for m in args.modes.split(",") if m)
+    modes = args.modes
     mutate = tuple(m for m in args.mutate.split(",") if m)
 
     if args.replay:

@@ -563,7 +563,7 @@ def mutate_scenario(scenario: Scenario, rng: np.random.Generator,
     is what made the case's coverage signature novel; the mutations search
     the *neighbourhood* of that behaviour — PRNG seed, coherence costs,
     horizon, active-thread count (reduce-only, so the probed layout stays an
-    upper bound for every invariant), the pinned scheduler/pallas placement,
+    upper bound for every invariant), the pinned scheduler placement,
     a redraw of the fault schedule when the case carries one,
     and — for ticket-family locks — re-seeding the ticket/grant counters
     just below ``INT32_MAX`` so the mutant crosses the wrap even if its
@@ -578,9 +578,9 @@ def mutate_scenario(scenario: Scenario, rng: np.random.Generator,
     the lock assembly is intact).
     """
     # deferred import: runner imports generate at module level
-    from .runner import PALLAS_CHUNK_POOL, SCHED_GEOMETRY_POOL
+    from .runner import SCHED_GEOMETRY_POOL
     s = scenario
-    ops = ["seed", "costs", "horizon", "sched_geometry", "pallas_chunk"]
+    ops = ["seed", "costs", "horizon", "sched_geometry"]
     if s.n_active > 2:
         ops.append("n_active")
     if s.kind == "composed" and s.lock in WRAP_SEED_LOCKS:
@@ -607,9 +607,6 @@ def mutate_scenario(scenario: Scenario, rng: np.random.Generator,
             g = SCHED_GEOMETRY_POOL[
                 int(rng.integers(len(SCHED_GEOMETRY_POOL)))]
             s = s.replace(meta={**s.meta, "sched_geometry": list(g)})
-        elif op == "pallas_chunk":
-            ch = PALLAS_CHUNK_POOL[int(rng.integers(len(PALLAS_CHUNK_POOL)))]
-            s = s.replace(meta={**s.meta, "pallas_chunk": int(ch)})
         elif op == "faults":
             s = with_fault_schedule(s, rng)
         elif op == "splice":

@@ -19,7 +19,7 @@ must report for it (``meta["expect_classes"]``), replayed by
     checker's own sensitivity.
   * ``wrap_*`` — composed scenarios whose ticket/grant counters start a
     couple of draws below ``INT32_MAX`` and wrap mid-run.  They must
-    replay with ZERO problems across all four sweep modes — these pin the
+    replay with ZERO problems across all three sweep drivers — these pin the
     wrap-safe ``SPIN_GE`` frontier compare and the wrap-aware
     conservation/FIFO accounting.
 

@@ -1,5 +1,5 @@
-"""Differential runner: oracle vs ``run_sweep`` across all four sweep
-modes, invariant checking, greedy shrinking, and the replayable corpus.
+"""Differential runner: oracle vs ``run_sweep`` across the three sweep
+drivers, invariant checking, greedy shrinking, and the replayable corpus.
 
 A fuzz batch is executed exactly like a figure sweep: every scenario is
 padded to the batch-shared shapes at generation time, so each engine mode
@@ -16,19 +16,16 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from .. import engine
-from ..engine_pallas import DEFAULT_PALLAS_CHUNK
 from ..faults import FaultSchedule, stack_schedules
 from .batch_oracle import run_batch_oracle
 from .generate import Scenario, scenario_faults
 from .invariants import check_invariants
 from .oracle import Trace, run_oracle
 
-MODES = ("map", "vmap", "sched", "pallas")
+MODES = engine.DRIVERS
 
 # Stats compared bit-identically between oracle and every engine mode.
-STAT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
-             "handover_count", "events", "sleeping", "grant_value",
-             "lat_hist")
+STAT_KEYS = engine.OUT_KEYS
 
 # Scheduler-geometry pool for fuzz batches.  The differential must exercise
 # the lane scheduler itself, not just the default 4×512 point: chunk=1
@@ -38,13 +35,6 @@ STAT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
 SCHED_GEOMETRY_POOL = ((1, 1), (2, 64), (3, 1), (6, 128),
                        (engine.DEFAULT_LANES, engine.DEFAULT_CHUNK),
                        (engine.TPU_LANES, engine.TPU_CHUNK))
-
-# Burst-chunk pool for the pallas driver.  chunk=1 terminates the in-kernel
-# while_loop after every single step (no overshoot), 16 overshoots on
-# nearly every cell, and the default amortizes the termination check; the
-# driver must be chunk-independent bit for bit (overshoot steps are
-# identity no-events), so any chunk-dependent difference IS a bug.
-PALLAS_CHUNK_POOL = (1, 16, DEFAULT_PALLAS_CHUNK)
 
 
 def sched_geometries(n_cases: int, seed: int) -> list[tuple[int, int]]:
@@ -76,72 +66,30 @@ def stamp_sched_geometry(scenarios: list[Scenario],
             for s, g in zip(scenarios, geoms)]
 
 
-def pallas_chunks(n_cases: int, seed: int) -> list[int]:
-    """Deterministic per-case pallas burst-chunk draws for a fuzz batch.
-
-    The pallas analogue of :func:`sched_geometries`: cases sharing a chunk
-    dispatch together, so a batch costs at most ``len(PALLAS_CHUNK_POOL)``
-    pallas compiles.
-    """
-    rng = np.random.default_rng(np.uint32(seed) ^ np.uint32(0xA77A5))
-    picks = rng.integers(0, len(PALLAS_CHUNK_POOL), n_cases)
-    return [PALLAS_CHUNK_POOL[int(i)] for i in picks]
-
-
-def stamp_pallas_chunk(scenarios: list[Scenario],
-                       sched_seed: int) -> list[Scenario]:
-    """Pin each scenario's drawn burst chunk into ``meta["pallas_chunk"]``.
-
-    Same replayability story as :func:`stamp_sched_geometry`: the draw
-    depends on batch position, so a chunk-dependent failure artifact must
-    carry the chunk it failed at.  Already-stamped scenarios (replayed
-    artifacts) keep theirs.
-    """
-    chunks = pallas_chunks(len(scenarios), sched_seed)
-    return [s if s.meta.get("pallas_chunk") is not None
-            else s.replace(meta={**s.meta, "pallas_chunk": int(ch)})
-            for s, ch in zip(scenarios, chunks)]
-
-
 def run_engine_batch(scenarios: list[Scenario], mode: str,
                      sched_seed: int = 0) -> list[dict]:
     """One compiled ``engine.run_sweep`` call over a padded batch.
 
     ``mode="sched"`` runs each case at its pinned ``meta["sched_geometry"]``
     (falling back to a fresh :func:`sched_geometries` draw seeded by
-    ``sched_seed``); ``mode="pallas"`` likewise at its pinned
-    ``meta["pallas_chunk"]`` (fallback :func:`pallas_chunks`).  Both
-    dispatch one sub-batch per distinct geometry, reassembling results in
-    input order.
+    ``sched_seed``), one sub-batch per distinct geometry, reassembling
+    results in input order.
     """
     s0 = scenarios[0]
     for s in scenarios:
         assert (s.n_threads, s.mem_words, s.n_locks) == \
             (s0.n_threads, s0.mem_words, s0.n_locks), "batch not padded"
-    if mode == "sched":
-        draws = sched_geometries(len(scenarios), sched_seed)
-        geoms = [tuple(s.meta["sched_geometry"])
-                 if s.meta.get("sched_geometry") is not None else g
-                 for s, g in zip(scenarios, draws)]
-        return _dispatch_grouped(scenarios, mode, geoms,
-                                 lambda g: dict(lanes=g[0], chunk=g[1]))
-    if mode == "pallas":
-        draws = pallas_chunks(len(scenarios), sched_seed)
-        chunks = [int(s.meta["pallas_chunk"])
-                  if s.meta.get("pallas_chunk") is not None else ch
-                  for s, ch in zip(scenarios, draws)]
-        return _dispatch_grouped(scenarios, mode, chunks,
-                                 lambda ch: dict(chunk=ch))
-    return _dispatch_batch(scenarios, mode)
-
-
-def _dispatch_grouped(scenarios, mode, keys, kwargs_of) -> list[dict]:
-    """Dispatch one sub-batch per distinct geometry key, in input order."""
+    if mode != "sched":
+        return _dispatch_batch(scenarios, mode)
+    draws = sched_geometries(len(scenarios), sched_seed)
+    geoms = [tuple(s.meta["sched_geometry"])
+             if s.meta.get("sched_geometry") is not None else g
+             for s, g in zip(scenarios, draws)]
     out: list = [None] * len(scenarios)
-    for key in sorted(set(keys)):
-        idxs = [i for i, k in enumerate(keys) if k == key]
+    for lanes, chunk in sorted(set(geoms)):
+        idxs = [i for i, g in enumerate(geoms) if g == (lanes, chunk)]
         sub = _dispatch_batch([scenarios[i] for i in idxs], mode,
-                              **kwargs_of(key))
+                              lanes=lanes, chunk=chunk)
         for i, res in zip(idxs, sub):
             out[i] = res
     return out
@@ -233,10 +181,10 @@ def fuzz(scenarios: list[Scenario], modes: tuple = MODES,
     """Differential + invariant sweep over a padded scenario batch.
 
     ``sched_seed`` seeds the per-case geometry draws of the ``"sched"``
-    mode (lanes x chunk) and the ``"pallas"`` mode (burst chunk).  The
-    drawn geometry is stamped into each scenario's meta up front, so a
-    failing case's artifact — and every shrink candidate derived from it —
-    replays at exactly the placement that failed.
+    mode (lanes x chunk).  The drawn geometry is stamped into each
+    scenario's meta up front, so a failing case's artifact — and every
+    shrink candidate derived from it — replays at exactly the placement
+    that failed.
 
     ``batch_oracle=True`` runs the oracle side through
     :func:`run_batch_oracle` (one vectorized pass instead of B sequential
@@ -247,7 +195,6 @@ def fuzz(scenarios: list[Scenario], modes: tuple = MODES,
     map and the indices of signature-novel cases land in ``report.novel``.
     """
     scenarios = stamp_sched_geometry(scenarios, sched_seed)
-    scenarios = stamp_pallas_chunk(scenarios, sched_seed)
     engine_outs = {mode: run_engine_batch(scenarios, mode,
                                           sched_seed=sched_seed)
                    for mode in modes}
@@ -338,7 +285,6 @@ def steer(n_cases: int, seed: int, modes: tuple = MODES,
         # stamp before fuzz so promoted scenarios carry their placement
         # pins (fuzz re-stamps idempotently)
         batch = stamp_sched_geometry(batch, seed + round_i)
-        batch = stamp_pallas_chunk(batch, seed + round_i)
         sub = fuzz(batch, modes=modes, sched_seed=seed + round_i,
                    batch_oracle=True, coverage=coverage)
         pool.extend(batch[i] for i in sub.novel)

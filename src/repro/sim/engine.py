@@ -50,26 +50,21 @@ Structure (batched-sweep refactor):
     geometry for the scheduler).  Everything else — program contents, costs,
     waiting-array geometry, horizon — is a traced input, so sweeping any of
     those axes reuses one executable.
-  * :func:`run_sweep` — batched sweep in ONE compiled call, four drivers:
-    ``mode="vmap"`` (lane-parallel, every cell is a lane), ``mode="map"``
-    (sequential cells), ``mode="sched"`` — a chunked work-stealing lane
-    scheduler (:func:`_make_run_sched`): ``lanes`` lanes step in fixed-size
-    chunks inside an outer while loop, and a lane whose cell finished is
-    refilled from the queue of not-yet-started cells, longest first.  A
-    skewed sweep then costs ~``sum(events)`` lane-steps instead of vmap's
-    ``max(events) × B``, while per-cell results stay bit-identical to
-    ``mode="map"`` (each cell still executes its private event sequence —
-    only lane placement changes).  ``mode="pallas"``
-    (:mod:`repro.sim.engine_pallas`) fuses the whole per-cell event loop
-    into one Pallas kernel grid step — hot state
-    resident in kernel memory across a ``chunk``-event burst instead of a
-    per-event ``lax.while_loop`` carry; it runs in interpret mode on CPU and
-    does not lower for TPU yet (see :data:`repro.sim.engine_pallas.
-    TPU_REFUSAL`).  ``mode="auto"`` (:func:`choose_mode`) picks a driver
-    from the backend kind plus the sweep shape.  Cells with fewer threads
-    than the batch maximum mask the excess threads inactive (``next_time =
-    INF`` forever), which leaves their per-event behaviour bit-identical to
-    an unpadded run.
+  * :func:`run_sweep` — batched sweep in ONE compiled call, three drivers
+    (:data:`DRIVERS`): ``mode="vmap"`` (lane-parallel, every cell is a
+    lane), ``mode="map"`` (sequential cells), ``mode="sched"`` — a chunked
+    work-stealing lane scheduler (:func:`_make_run_sched`): ``lanes`` lanes
+    step in fixed-size chunks inside an outer while loop, and a lane whose
+    cell finished is refilled from the queue of not-yet-started cells,
+    longest first.  A skewed sweep then costs ~``sum(events)`` lane-steps
+    instead of vmap's ``max(events) × B``, while per-cell results stay
+    bit-identical to ``mode="map"`` (each cell still executes its private
+    event sequence — only lane placement changes).  Every driver returns
+    the per-cell stats :data:`OUT_KEYS`.  ``mode="auto"``
+    (:func:`choose_mode`) picks a driver from the backend kind plus the
+    sweep shape.  Cells with fewer threads than the batch maximum mask the
+    excess threads inactive (``next_time = INF`` forever), which leaves
+    their per-event behaviour bit-identical to an unpadded run.
 """
 
 from __future__ import annotations
@@ -737,6 +732,30 @@ def _fault_fields(faults) -> dict:
     return dict(zip(("f_kind", "f_evt", "f_tid", "f_arg"), faults))
 
 
+# The sweep drivers, by their ``mode`` names.
+DRIVERS = ("map", "vmap", "sched")
+
+# The sweep-output contract: the per-cell stats every driver returns (each
+# with a leading cell axis in a sweep), beside its ``loop_iters`` counter.
+OUT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
+            "handover_count", "events", "sleeping", "grant_value",
+            "lat_hist")
+
+
+def _cell_stats(s: SimState):
+    """Yield ``(key, value)`` for each of :data:`OUT_KEYS` from a final
+    state, cells on the leading axis where batched.  Lazily, so a driver
+    that writes each value into its slot traces them one after another."""
+    yield "acquisitions", s.acq
+    yield "waited_acquisitions", s.waited_acq
+    yield "handover_sum", s.hand_sum
+    yield "handover_count", s.hand_cnt
+    yield "events", s.events
+    yield "sleeping", (s.spin_addr >= 0).sum(-1)
+    yield "grant_value", s.mem  # full memory; callers slice what they need
+    yield "lat_hist", s.lat_hist
+
+
 def _make_run(n_threads: int, mem_words: int, n_locks: int):
     """While-loop driver over the single-event step for one shape set.
 
@@ -764,17 +783,7 @@ def _make_run(n_threads: int, mem_words: int, n_locks: int):
         s0 = _initial_state(n_threads, mem_words, n_locks, init_pc, init_regs,
                             init_mem, n_active, seed)
         final, iters = jax.lax.while_loop(cond, body, (s0, jnp.int32(0)))
-        return {
-            "acquisitions": final.acq,
-            "waited_acquisitions": final.waited_acq,
-            "handover_sum": final.hand_sum,
-            "handover_count": final.hand_cnt,
-            "events": final.events,
-            "sleeping": (final.spin_addr >= 0).sum(),
-            "grant_value": final.mem,  # full memory; callers slice what they need
-            "lat_hist": final.lat_hist,
-            "loop_iters": iters,
-        }
+        return dict(_cell_stats(final), loop_iters=iters)
 
     return lockvm_cell
 
@@ -839,17 +848,7 @@ def _make_run_batched(n_threads: int, mem_words: int, n_locks: int):
             return vstep(c, s), n + 1
 
         final, iters = jax.lax.while_loop(cond, body, (s0, jnp.int32(0)))
-        return {
-            "acquisitions": final.acq,
-            "waited_acquisitions": final.waited_acq,
-            "handover_sum": final.hand_sum,
-            "handover_count": final.hand_cnt,
-            "events": final.events,
-            "sleeping": (final.spin_addr >= 0).sum(1),
-            "grant_value": final.mem,
-            "lat_hist": final.lat_hist,
-            "loop_iters": iters,
-        }
+        return dict(_cell_stats(final), loop_iters=iters)
 
     return lockvm_vmap
 
@@ -936,26 +935,8 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
                 | (jnp.minimum(t_th, t_cm) >= c.horizon))
             # scatter finished stats to their cell slot (index B = dropped)
             idx = jnp.where(fin, lane_cell, n_cells)
-            outs = {
-                "acquisitions":
-                    outs["acquisitions"].at[idx].set(s.acq, mode="drop"),
-                "waited_acquisitions":
-                    outs["waited_acquisitions"].at[idx].set(s.waited_acq,
-                                                            mode="drop"),
-                "handover_sum":
-                    outs["handover_sum"].at[idx].set(s.hand_sum, mode="drop"),
-                "handover_count":
-                    outs["handover_count"].at[idx].set(s.hand_cnt,
-                                                       mode="drop"),
-                "events": outs["events"].at[idx].set(s.events, mode="drop"),
-                "sleeping":
-                    outs["sleeping"].at[idx].set((s.spin_addr >= 0).sum(1),
-                                                 mode="drop"),
-                "grant_value":
-                    outs["grant_value"].at[idx].set(s.mem, mode="drop"),
-                "lat_hist":
-                    outs["lat_hist"].at[idx].set(s.lat_hist, mode="drop"),
-            }
+            outs = {k: outs[k].at[idx].set(v, mode="drop")
+                    for k, v in _cell_stats(s)}
             # work stealing: the i-th finished lane (in lane order) claims
             # queue slot next_slot + i; lanes past the queue end park
             rank = jnp.cumsum(fin.astype(jnp.int32)) - fin.astype(jnp.int32)
@@ -972,16 +953,11 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
             return lane_cell, next_slot, s, outs, bursts + 1
 
         lane_cell0 = order[:lanes]
-        outs0 = {
-            "acquisitions": jnp.zeros((n_cells, n_threads), jnp.int32),
-            "waited_acquisitions": jnp.zeros((n_cells, n_threads), jnp.int32),
-            "handover_sum": jnp.zeros(n_cells, jnp.int32),
-            "handover_count": jnp.zeros(n_cells, jnp.int32),
-            "events": jnp.zeros(n_cells, jnp.int32),
-            "sleeping": jnp.zeros(n_cells, jnp.int32),
-            "grant_value": jnp.zeros((n_cells, mem_words), jnp.int32),
-            "lat_hist": jnp.zeros((n_cells, N_LAT_BUCKETS), jnp.int32),
-        }
+        # a zero row a cell for each stat, shaped by an abstract trace
+        stats = jax.eval_shape(
+            lambda lc: dict(_cell_stats(jax.vmap(cell_init)(lc))), lane_cell0)
+        outs0 = {k: jnp.zeros((n_cells,) + stats[k].shape[1:], stats[k].dtype)
+                 for k in OUT_KEYS}
         carry = (lane_cell0, jnp.int32(lanes),
                  jax.vmap(cell_init)(lane_cell0), outs0, jnp.int32(0))
         *_, outs, bursts = jax.lax.while_loop(cond, body, carry)
@@ -993,35 +969,24 @@ def _make_run_sched(n_threads: int, mem_words: int, n_locks: int,
 @functools.lru_cache(maxsize=256)
 def _build_engine(n_threads: int, mem_words: int, n_locks: int, prog_len: int,
                   batched: str | None = None, n_lanes: int = 0,
-                  chunk: int = 0, interpret: bool = False,
-                  n_faults: int = 0):
+                  chunk: int = 0, n_faults: int = 0):
     """Compile an engine for a given shape set (everything else is an input).
 
     The cache key is shapes only; ``prog_len`` rides along for cache identity
     even though jit would also specialize on it.  ``batched`` selects the
     sweep driver ("vmap" = lane-parallel, "map" = sequential cells, "sched" =
     work-stealing lanes keyed additionally on the ``n_lanes``/``chunk``
-    geometry, "pallas" = the fused-kernel fast path keyed on ``chunk`` and
-    the ``interpret`` flag); either way a sweep is one compile and one
-    dispatch, not one per cell.  ``n_faults`` is the fault-schedule capacity:
-    0 builds the fault-free step (no fault code traced at all); > 0 drivers
-    take four trailing ``(B, n_faults)`` schedule arrays.  Each driver's
-    function has a name of its own (``lockvm_cell``, ``lockvm_map``,
-    ``lockvm_vmap``, ``lockvm_sched``, ``lockvm_pallas``), which JAX's
-    compile and execution events carry.
+    geometry); either way a sweep is one compile and one dispatch, not one
+    per cell.  ``n_faults`` is the fault-schedule capacity: 0 builds the
+    fault-free step (no fault code traced at all); > 0 drivers take four
+    trailing ``(B, n_faults)`` schedule arrays.  Each driver's function has
+    a name of its own (``lockvm_cell``, ``lockvm_map``, ``lockvm_vmap``,
+    ``lockvm_sched``), which JAX's compile and execution events carry.
     """
     if batched == "sched":
-        assert not interpret, "interpret only applies to mode='pallas'"
         return jax.jit(_make_run_sched(n_threads, mem_words, n_locks,
                                        n_lanes, chunk))
-    if batched == "pallas":
-        from .engine_pallas import make_run_pallas
-        assert n_lanes == 0, (batched, n_lanes)
-        return jax.jit(make_run_pallas(n_threads, mem_words, n_locks,
-                                       prog_len, chunk, interpret,
-                                       n_faults=n_faults))
-    assert n_lanes == 0 and chunk == 0 and not interpret, \
-        (batched, n_lanes, chunk, interpret)
+    assert n_lanes == 0 and chunk == 0, (batched, n_lanes, chunk)
     if batched == "vmap":
         return jax.jit(_make_run_batched(n_threads, mem_words, n_locks))
     if batched == "map":
@@ -1215,9 +1180,8 @@ def choose_mode(backend: str, *, n_cells: int, n_threads: int,
       host backends run them one after another (``"map"``): a scalar step
       sees no SIMD benefit there, so ``"map"`` pays exactly ``sum(events)``.
 
-    ``"pallas"`` is never picked: Mosaic refuses its kernel
-    (:data:`repro.sim.engine_pallas.TPU_REFUSAL`).  Work per cell is
-    estimated as ``horizon × n_active`` (:func:`_work_estimate`).
+    Work per cell is estimated as ``horizon × n_active``
+    (:func:`_work_estimate`).
     """
     est = _work_estimate(n_cells, horizon,
                          n_threads if n_active is None else n_active)
@@ -1234,7 +1198,7 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
               horizon, max_events=2_000_000, costs=None,
               init_mem: np.ndarray | None = None,
               mode: str = "auto", lanes: int | None = None,
-              chunk: int | None = None, interpret: bool | None = None,
+              chunk: int | None = None,
               live_mem_words=None, faults=None) -> dict:
     """Run a batch of independent simulations as ONE compiled, vmapped call.
 
@@ -1261,18 +1225,14 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         with uniform cells), "map" runs them sequentially inside one compiled
         program, "sched" runs a work-stealing lane scheduler (pays
         ~sum(events) like "map" but keeps ``lanes`` cells in flight — the
-        right choice for skewed sweeps), "pallas" fuses each cell's whole
-        event loop into one Pallas-kernel grid step (interpret mode only:
-        natively it raises ``NotImplementedError``, as Mosaic refuses the
-        kernel), "auto" picks by backend kind + sweep shape
-        (:func:`choose_mode`).  Results are bit-identical across all modes.
-      lanes/chunk: driver geometry — ``lanes`` ("sched" only) is the number
-        of parallel work-stealing lanes (clamped to B); ``chunk`` ("sched"
-        and "pallas") is the steps per burst between termination checks.
-        Under "sched" either left None comes from :func:`sched_geometry`;
-        the queue hands out cells longest first (:func:`queue_order`).
-      interpret: "pallas" only — force the Pallas interpreter on/off; None
-        autodetects (interpret unless the backend is a TPU).
+        right choice for skewed sweeps), "auto" picks by backend kind +
+        sweep shape (:func:`choose_mode`).  Results are bit-identical across
+        all modes.  Any other name raises ``ValueError``.
+      lanes/chunk: "sched" only (``ValueError`` under another driver) —
+        ``lanes`` is the number of parallel work-stealing lanes (clamped to
+        B) and ``chunk`` the steps per burst between termination checks;
+        either left None comes from :func:`sched_geometry`.  The queue hands
+        out cells longest first (:func:`queue_order`).
       live_mem_words: optional (B,) per-cell *unpadded* memory sizes, used
         only for the ``pad_stats`` waste report (defaults to ``mem_words``,
         i.e. no padding assumed).
@@ -1282,9 +1242,10 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         builds the fault-free step: zero-fault sweeps are bit-identical to
         the pre-fault-subsystem engine.
 
-    Returns a dict of stacked numpy arrays: per-thread stats have shape
-    (B, n_threads), scalars (B,), and ``grant_value`` (B, mem_words) holds
-    each cell's final memory.  Two bookkeeping keys ride along: ``mode``
+    Returns a dict of stacked numpy arrays, one per :data:`OUT_KEYS`:
+    per-thread stats have shape (B, n_threads), scalars (B,), and
+    ``grant_value`` (B, mem_words) holds each cell's final memory.  Two
+    bookkeeping keys ride along: ``mode``
     (the resolved driver, useful under "auto") and ``pad_stats`` — the
     sweep's padding-waste report (``sum_events``/``max_events``, the
     live thread/program/memory fractions of the padded batch, and the
@@ -1315,30 +1276,19 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         log.info("run_sweep mode='auto' -> %r (backend=%s, B=%d, "
                  "n_threads=%d, mem_words=%d)", mode, backend, n_cells,
                  n_threads, mem_words)
-    assert mode in ("vmap", "map", "sched", "pallas"), mode
+    if mode not in DRIVERS:
+        raise ValueError(f"mode must be 'auto' or one of {DRIVERS}, "
+                         f"got {mode!r}")
     if mode == "sched":
         geometry = sched_geometry(jax.default_backend(), n_cells)
         lanes = geometry[0] if lanes is None else lanes
         chunk = geometry[1] if chunk is None else chunk
         assert lanes >= 1 and chunk >= 1, (lanes, chunk)
-    elif mode == "pallas":
-        from ..kernels import resolve_interpret
-        from .engine_pallas import DEFAULT_PALLAS_CHUNK, TPU_REFUSAL
-        assert lanes is None, "lanes only applies to mode='sched'"
-        lanes = 0
-        chunk = DEFAULT_PALLAS_CHUNK if chunk is None else chunk
-        assert chunk >= 1, chunk
-        interpret = resolve_interpret(interpret)
-        if not interpret:
-            raise NotImplementedError(TPU_REFUSAL)
+    elif lanes is not None or chunk is not None:
+        raise ValueError(f"lanes and chunk only apply to mode='sched', "
+                         f"got mode={mode!r}")
     else:
-        assert lanes is None and chunk is None, \
-            f"lanes/chunk only apply to mode='sched'/'pallas', " \
-            f"got mode={mode!r}"
         lanes = chunk = 0
-    if mode != "pallas":
-        assert interpret is None, "interpret only applies to mode='pallas'"
-        interpret = False
 
     wa_size_arr = _broadcast_cells(wa_size, n_cells, np.int32)
     assert (wa_size_arr & (wa_size_arr - 1) == 0).all(), "wa_size must be pow2"
@@ -1377,7 +1327,7 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
                          queue="longest_first" if queue else "none"):
         engine = _build_engine(n_threads, mem_words, n_locks, prog_len,
                                batched=mode, n_lanes=lanes, chunk=chunk,
-                               interpret=interpret, n_faults=n_faults)
+                               n_faults=n_faults)
         out = engine(
             jnp.asarray(programs), jnp.asarray(init_pc),
             jnp.asarray(init_regs), jnp.asarray(init_mem),
@@ -1394,8 +1344,8 @@ def run_sweep(programs: np.ndarray, *, mem_words: int, n_locks: int,
         res = {k: np.asarray(v) for k, v in out.items()}
     loop_iters = res.pop("loop_iters")
     # lanes stepped together, times the steps each ran: a loop iteration of
-    # sched or pallas is a burst of ``chunk`` steps (chunk is 0 elsewhere),
-    # and map and pallas count per cell
+    # sched is a burst of ``chunk`` steps (chunk is 0 elsewhere), and map
+    # counts per cell
     lanes_per_step = {"vmap": n_cells, "sched": min(lanes, n_cells)}.get(mode, 1)
     lane_steps = (lanes_per_step * max(chunk, 1)
                   * int(loop_iters.sum(dtype=np.int64)))

@@ -169,8 +169,8 @@ class SweepSpec:
 
 
 def run_sweep(spec: SweepSpec, *, mode: str = "auto",
-              lanes: int | None = None, chunk: int | None = None,
-              interpret: bool | None = None) -> list[dict]:
+              lanes: int | None = None,
+              chunk: int | None = None) -> list[dict]:
     """Run every cell of ``spec`` in one compiled call.
 
     Returns one dict per cell, in :meth:`SweepSpec.cells` order.  Each dict
@@ -180,10 +180,10 @@ def run_sweep(spec: SweepSpec, *, mode: str = "auto",
     ...), with per-thread arrays sliced to the cell's real thread count,
     plus the sweep-wide ``mode`` (the resolved driver) and ``pad_stats``
     (padding-waste report) bookkeeping.  ``mode`` selects the batched
-    execution strategy (see :func:`repro.sim.engine.run_sweep`; the default
-    ``"auto"`` picks per backend + sweep shape; ``lanes``/``chunk``
-    configure the ``"sched"`` work-stealing driver, ``chunk``/``interpret``
-    the ``"pallas"`` fused kernel); results are mode-independent.
+    execution strategy, one of ``"map"``, ``"vmap"`` and ``"sched"`` (see
+    :func:`repro.sim.engine.run_sweep`; the default ``"auto"`` picks per
+    backend + sweep shape; ``lanes``/``chunk`` configure the ``"sched"``
+    work-stealing driver); results are mode-independent.
 
     Host spans on the profiler's clock, nested in ``lockvm.sweep``: the
     cells' programs, layouts and fault schedules (``lockvm.build``), their
@@ -225,7 +225,7 @@ def run_sweep(spec: SweepSpec, *, mode: str = "auto",
             seeds=seeds, wa_base=wa_base, wa_size=wa_size,
             horizon=spec.horizon, max_events=spec.max_events,
             costs=costs, init_mem=init_mem,
-            mode=mode, lanes=lanes, chunk=chunk, interpret=interpret,
+            mode=mode, lanes=lanes, chunk=chunk,
             live_mem_words=live_mem_words, faults=faults,
         )
         with TraceAnnotation("lockvm.assemble"):

@@ -4,8 +4,8 @@ regression cases.
 Two families, distinguished by the expected-class pin each entry carries:
   * ``diff_*``: scenarios historically shrunk under an injected oracle
     mutation (store visibility, lost wakeups, free invalidation).  On the
-    correct engine they must replay with ZERO problems across all four
-    sweep modes (``pallas`` in interpret mode) — they pin exactly the
+    correct engine they must replay with ZERO problems across all three
+    sweep drivers — they pin exactly the
     engine behaviours those mutations would break.
   * ``inv_*``: deliberately broken lock programs.  The checker must KEEP
     reporting the recorded invariant classes — they pin the checker's own
@@ -14,8 +14,8 @@ Two families, distinguished by the expected-class pin each entry carries:
   * ``fault_*``: composed scenarios carrying scheduled fault injections
     (preemption windows, spurious wakeups, a thread abort, and a
     timed-lock abandonment case under preemption) whose every fault lands
-    inside the run.  They must replay with ZERO problems across all four
-    sweep modes — they pin the fault semantics of the engine, both oracles
+    inside the run.  They must replay with ZERO problems across all three
+    sweep drivers — they pin the fault semantics of the engine, both oracles
     and the C fast path against each other.
 
 Regenerate with ``python -m repro.sim.check.make_corpus tests/corpus``

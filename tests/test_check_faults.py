@@ -1,5 +1,5 @@
 """Fault injection end-to-end: schedule plumbing, zero-fault bit-identity,
-fault-enabled differential fuzz across all four sweep modes, the
+fault-enabled differential fuzz across all three sweep drivers, the
 ``dropped_fault`` checker self-test, the robustness invariant classes
 (``lost_grant`` / ``recovery`` / ``abandoned``), the timed/abortable
 ``twa-timo`` lock's in-VM abandonment books, and the program-splicing
@@ -144,7 +144,7 @@ def test_sweep_fault_schedules_are_coordinate_keyed():
 
 def test_fault_fuzz_is_clean_across_all_modes(fault_batch):
     """The acceptance sweep in miniature, faults on: oracle stats ==
-    run_sweep stats bit-identically across map/vmap/sched/pallas with
+    run_sweep stats bit-identically across map/vmap/sched with
     every case carrying a drawn fault schedule."""
     report = fuzz(fault_batch)
     assert report.ok, report.summary()

@@ -1,5 +1,5 @@
 """Differential fuzzer end-to-end: generator well-formedness, oracle vs
-run_sweep bit-equality across all four sweep modes, invariants on composed
+run_sweep bit-equality across all three sweep drivers, invariants on composed
 scenarios, and the mutation self-test (an injected store-visibility engine
 bug must be caught and shrunk to a dozen instructions or fewer)."""
 
@@ -74,7 +74,7 @@ def test_random_programs_are_well_formed(batch):
 
 def test_fuzz_batch_differential_and_invariants(batch):
     """The acceptance sweep in miniature: oracle stats == run_sweep stats
-    bit-identically across map/vmap/sched/pallas, and every invariant
+    bit-identically across map/vmap/sched, and every invariant
     holds."""
     report = fuzz(batch)
     assert report.ok, report.summary()
@@ -171,48 +171,28 @@ def test_sched_geometry_is_pinned_into_scenarios_for_replay(batch, tmp_path):
     assert load_scenario(path).meta["sched_geometry"] == pins[0]
 
 
-def test_pallas_chunk_varies_across_a_fuzz_batch():
-    """The pallas analogue of the sched-geometry draws: per-case burst
-    chunks must be deterministic in the seed, cover several pool entries,
-    and include the chunk=1 no-overshoot edge."""
-    from repro.sim.check import PALLAS_CHUNK_POOL, pallas_chunks
-    chunks = pallas_chunks(32, seed=11)
-    assert chunks == pallas_chunks(32, seed=11)         # deterministic
-    assert chunks != pallas_chunks(32, seed=12)         # seed-sensitive
-    assert set(chunks) <= set(PALLAS_CHUNK_POOL)
-    assert len(set(chunks)) == len(PALLAS_CHUNK_POOL)   # actually varies
-    assert 1 in chunks                                  # chunk=1 edge
+def test_artifact_with_a_pallas_chunk_replays(batch, tmp_path):
+    """Artifacts saved while the checker also ran a burst-chunked kernel
+    driver carry ``meta["pallas_chunk"]``; they still load and replay
+    clean under every mode, the key ignored."""
+    from repro.sim.check import MODES
+    s = batch[0]
+    path = tmp_path / "old.npz"
+    save_scenario(path, s.replace(meta={**s.meta, "pallas_chunk": 16}))
+    loaded = load_scenario(path)
+    assert loaded.meta["pallas_chunk"] == 16
+    assert case_problems(loaded, modes=MODES) == []
 
 
-def test_pallas_randomized_chunk_matches_map(batch):
-    """Randomized burst chunking must not change any stat: pallas results
-    (grouped by drawn chunk) stay bit-identical to the sequential map
-    driver for every case."""
-    from repro.sim.check import run_engine_batch
-    sub = batch[:6]
-    ref = run_engine_batch(sub, "map")
-    for sched_seed in (0, 9):
-        got = run_engine_batch(sub, "pallas", sched_seed=sched_seed)
-        for r, g in zip(ref, got):
-            for k in ("acquisitions", "events", "grant_value"):
-                assert np.array_equal(r[k], g[k]), (sched_seed, k)
-
-
-def test_pallas_chunk_is_pinned_into_scenarios_for_replay(batch, tmp_path):
-    """A chunk-dependent failure must be reproducible from its own
-    artifact: fuzz() stamps each case's drawn burst chunk into the
-    scenario meta, a pinned chunk survives re-stamping under a different
-    seed, and the corpus roundtrip keeps the pin."""
-    from repro.sim.check import PALLAS_CHUNK_POOL
-    from repro.sim.check.runner import stamp_pallas_chunk
-    stamped = stamp_pallas_chunk(batch[:4], sched_seed=3)
-    pins = [s.meta["pallas_chunk"] for s in stamped]
-    assert all(p in set(PALLAS_CHUNK_POOL) for p in pins)
-    again = stamp_pallas_chunk(stamped, sched_seed=99)
-    assert [s.meta["pallas_chunk"] for s in again] == pins
-    path = tmp_path / "pinned.npz"
-    save_scenario(path, stamped[0])
-    assert load_scenario(path).meta["pallas_chunk"] == pins[0]
+def test_cli_rejects_an_unknown_mode(capsys):
+    """``--modes`` names drivers; an unknown one is a usage error (exit
+    code 2, argparse's), not a traceback from the engine."""
+    from repro.sim.check.__main__ import main
+    with pytest.raises(SystemExit) as exc:
+        main(["--modes", "map,pallas", "--cases", "1"])
+    assert exc.value.code == 2
+    err = capsys.readouterr().err
+    assert "pallas" in err and "map, vmap, sched" in err
 
 
 def test_liveness_checker_convicts_a_starving_lock():

@@ -8,11 +8,12 @@ import numpy as np
 from repro.sim import SIM_LOCKS, Layout, build_mutexbench, \
     build_occupancy_probe, init_state
 from repro.sim.check import Trace, run_oracle
-from repro.sim.engine import EVENT_ORDER_CONTRACT, debug_states, run_sim
+from repro.sim.engine import (EVENT_ORDER_CONTRACT, OUT_KEYS, debug_states,
+                              run_sim)
 from repro.sim.programs import INIT_MEM_GEN, pad_program
 
-STAT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
-             "handover_count", "events", "sleeping")
+# run_sim returns the final memory as "mem", compared on its own
+STAT_KEYS = tuple(k for k in OUT_KEYS if k != "grant_value")
 H = 12_000
 
 

@@ -28,7 +28,6 @@ from repro.kernels.rglru.kernel import rglru_scan_pallas
 from repro.kernels.ticket_dispatch.kernel import ticket_dispatch_pallas
 from repro.models.model import decode_step, forward, init_cache, init_params
 from repro.sim import engine, isa
-from repro.sim.engine_pallas import make_run_pallas
 from repro.sim.programs import PROG_LEN
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[1]))
@@ -88,19 +87,10 @@ def _drivers(n_cells, n_threads, mem_words):
         # the geometry a TPU resolves for this sweep
         "sched": lambda: engine._make_run_sched(
             n_threads, mem_words, 1, *engine.sched_geometry("tpu", n_cells)),
-        "pallas": lambda: make_run_pallas(n_threads, mem_words, 1, PROG_LEN,
-                                          128, interpret=False),
     }
 
 
-@pytest.mark.parametrize("mode", [
-    "map", "vmap", "sched",
-    pytest.param("pallas", marks=pytest.mark.xfail(
-        strict=True, raises=ValueError,
-        reason="Mosaic: the (1, n_threads) and (1,) blocks break the "
-               "(8, 128) block-shape rule; past that, the int32 argmin in "
-               "engine._step: 'Only float32 is supported'")),
-])
+@pytest.mark.parametrize("mode", engine.DRIVERS)
 def test_sweep_driver_compiles_at_fig3_shape(one_chip, mode):
     n_cells, n_threads, mem_words = _fig3_shape()
     assert (n_cells, n_threads) == (273, 64)

@@ -1,7 +1,7 @@
 """Results store + lock advisor + per-acquisition latency percentiles +
 outside_work axis: the PR-9 subsystem end to end.
 
-Covers: latency-histogram bit-identity across all four engine modes and
+Covers: latency-histogram bit-identity across all three engine drivers and
 both batch-oracle implementations (including a wrap-adjacent ticket
 case), percentile extraction semantics, the outside_work axis
 (reachability + throughput monotonicity), store round-trip / coordinate
@@ -68,7 +68,7 @@ def _latency_scenario(lock: str, *, seed: int = 7, ticket_base: int = 0,
 
 def test_lat_hist_bit_identical_across_modes_and_oracles():
     """lat_hist is in STAT_KEYS, so the differential harness enforces it:
-    TSTART-instrumented programs must agree across map/vmap/sched/pallas
+    TSTART-instrumented programs must agree across map/vmap/sched
     AND both batch-oracle implementations (NumPy and the C kernel),
     including a ticket lock seeded wrap-adjacent so the (now - t0) window
     spans tickets crossing the int32 wrap."""
@@ -76,7 +76,7 @@ def test_lat_hist_bit_identical_across_modes_and_oracles():
              for lock in ("ticket", "twa", "mcs", "anderson")]
     scens.append(_latency_scenario("ticket", seed=9,
                                    ticket_base=2**31 - 8))
-    report = fuzz(scens, modes=("map", "vmap", "sched", "pallas"))
+    report = fuzz(scens)
     assert report.ok, report.summary()
     report_b = fuzz(scens, modes=("map",), batch_oracle=True)
     assert report_b.ok, report_b.summary()

@@ -14,10 +14,8 @@ import pytest
 from repro.sim import SweepSpec
 from repro.sim.engine import engine_cache_info
 from repro.sim import engine
+from repro.sim.engine import OUT_KEYS
 from repro.sim.workloads import pack_engine_cells, run_sweep
-
-OUT_KEYS = ("acquisitions", "waited_acquisitions", "handover_sum",
-            "handover_count", "events", "sleeping", "grant_value")
 
 
 def _skewed_sweep_args():

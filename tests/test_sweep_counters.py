@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from repro.sim import SweepSpec, engine
-from repro.sim.engine_pallas import OUT_KEYS, make_run_pallas
+from repro.sim.engine import OUT_KEYS
 from repro.sim.programs import PROG_LEN
 from repro.sim.workloads import pack_engine_cells, run_sweep
 
@@ -29,7 +29,6 @@ DRIVERS = {
     "sched": {"lanes": 3, "chunk": 128},
     "sched_one": {"lanes": 1, "chunk": 1},
     "sched_wide": {"lanes": 32, "chunk": 64},   # more lanes than cells
-    "pallas": {"chunk": 16},
 }
 
 
@@ -84,14 +83,6 @@ def test_sched_one_lane_one_step_bursts_pay_one_step_per_event(sweeps):
     assert ps["lane_steps"] == ps["sum_events"] + never_ran
 
 
-def test_pallas_lane_steps_are_whole_bursts_per_cell(sweeps):
-    out = sweeps["pallas"]
-    ps = out["pad_stats"]
-    chunk = DRIVERS["pallas"]["chunk"]
-    bursts = -(-out["events"] // chunk)  # a cell that never ran takes none
-    assert (ps["lanes"], ps["lane_steps"]) == (1, int(bursts.sum()) * chunk)
-
-
 def test_lane_steps_are_exact_on_a_fault_free_spec_sweep():
     spec = SweepSpec(locks=("ticket", "twa"), threads=(1, 3), seeds=(1, 2),
                      horizon=3_000)
@@ -112,8 +103,6 @@ def test_each_driver_compiles_under_a_name_of_its_own():
         "lockvm_map": engine._make_run_map(n_threads, mem_words, 1),
         "lockvm_vmap": engine._make_run_batched(n_threads, mem_words, 1),
         "lockvm_sched": engine._make_run_sched(n_threads, mem_words, 1, 2, 8),
-        "lockvm_pallas": make_run_pallas(n_threads, mem_words, 1, PROG_LEN,
-                                         8, interpret=True),
     }
     for name, fn in drivers.items():
         assert fn.__name__ == name

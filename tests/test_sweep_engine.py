@@ -1,16 +1,23 @@
 """Batched-sweep engine validation: run_sweep must be bit-equivalent to
-sequential run_sim, shape padding must be invisible, and an entire sweep
-must cost a single engine compilation."""
+sequential run_sim, shape padding must be invisible, an entire sweep must
+cost a single engine compilation, ``mode="auto"`` must pick different
+drivers at different (backend, sweep-shape) points, an unknown driver or a
+geometry given to a driver without one must be refused, and every sweep
+must stamp its resolved mode and padding-waste report into the result."""
 
+import logging
+
+import jax
 import numpy as np
 import pytest
 
-from repro.sim import (SIM_LOCKS, SweepSpec, pad_program, pad_threads,
-                       run_contention, run_sweep)
-from repro.sim.engine import engine_cache_info, run_sim
+from repro.sim import (SIM_LOCKS, SweepSpec, choose_mode, engine, pad_program,
+                       pad_threads, run_contention, run_sweep)
+from repro.sim.engine import OUT_KEYS, engine_cache_info, run_sim
 from repro.sim.faults import FaultSchedule
 from repro.sim.programs import (INIT_MEM_GEN, Layout, build_mutexbench,
                                 init_state)
+from repro.sim.workloads import pack_engine_cells
 
 H = 120_000
 
@@ -174,3 +181,120 @@ def test_anderson_requires_private_arrays_for_multilock():
     acq = res["acquisitions"]
     assert acq.min() > 0
     assert acq.min() >= 0.8 * acq.max(), acq
+
+
+# ---------------------------------------------------------------------------
+# Driver choice, refusals and the padding-waste report
+# ---------------------------------------------------------------------------
+
+ON_CPU = jax.default_backend() == "cpu"
+
+
+def _skewed_sweep_args():
+    """One heavy cell towering over light ones, plus a zero-horizon cell."""
+    cells = [("twa", 6, 150_000), ("ticket", 2, 12_000), ("mcs", 3, 12_000),
+             ("ticket", 5, 20_000), ("twa", 2, 8_000), ("anderson", 4, 15_000),
+             ("ticket", 3, 0), ("twa", 4, 25_000)]
+    return pack_engine_cells(cells, ncs_max=100, seeds=5)
+
+
+def _assert_same(ref: dict, out: dict, ctx) -> None:
+    for key in OUT_KEYS:
+        assert np.array_equal(ref[key], out[key]), (ctx, key)
+
+
+def test_choose_mode_selects_distinct_drivers():
+    """The auto policy must pick different drivers at distinct
+    (backend, sweep-shape) points — the whole point of mode="auto"."""
+    uniform = dict(n_cells=4, n_threads=8, horizon=10_000)
+    skew_h = np.asarray([600_000] + [10_000] * 11)
+    skewed = dict(n_cells=12, n_threads=8, horizon=skew_h)
+    assert choose_mode("cpu", **uniform) == "map"
+    assert choose_mode("cpu", **skewed) == "sched"
+    assert choose_mode("tpu", **uniform) == "vmap"
+    assert choose_mode("tpu", **skewed) == "sched"
+    # the fig3 grid: one horizon, thread counts 1..64 -> skewed by n_active
+    fig3_active = np.repeat([1, 2, 4, 8, 16, 32, 64], 39)
+    assert choose_mode("tpu", n_cells=273, n_threads=64, horizon=1_500_000,
+                       n_active=fig3_active) == "sched"
+    # the skew gate needs enough cells for stealing to pay off
+    few = dict(n_cells=2, n_threads=8,
+               horizon=np.asarray([600_000, 10_000]))
+    assert choose_mode("cpu", **few) == "map"
+    assert choose_mode("tpu", **few) == "vmap"
+
+
+def test_auto_mode_on_tpu_backend_picks_xla_driver(monkeypatch):
+    """Where the backend reports a TPU, auto resolves to an XLA driver."""
+    monkeypatch.setattr(engine.jax, "default_backend", lambda: "tpu")
+    cells = [("ticket", 2, 10_000), ("twa", 2, 10_000)]
+    programs, kw = pack_engine_cells(cells, ncs_max=100, seeds=3)
+    out = engine.run_sweep(programs, mode="auto", **kw)
+    assert out["mode"] == "vmap"
+    monkeypatch.undo()
+    _assert_same(engine.run_sweep(programs, mode="map", **kw), out,
+                 "auto-tpu")
+
+
+@pytest.mark.skipif(not ON_CPU, reason="asserts the CPU auto policy")
+def test_auto_mode_resolves_by_sweep_shape(caplog):
+    """On the CPU backend, auto must resolve to different drivers for a
+    uniform vs a skewed sweep, log the choice, and stamp it in the result."""
+    programs, kw = _skewed_sweep_args()
+    with caplog.at_level(logging.INFO, logger="repro.sim.engine"):
+        out = engine.run_sweep(programs, mode="auto", **kw)
+    assert out["mode"] == "sched"
+    assert any("mode='auto' -> 'sched'" in r.getMessage()
+               for r in caplog.records)
+    cells = [("ticket", 2, 10_000), ("twa", 2, 10_000)]
+    programs2, kw2 = pack_engine_cells(cells, ncs_max=100, seeds=3)
+    out2 = engine.run_sweep(programs2, mode="auto", **kw2)
+    assert out2["mode"] == "map"
+    ref2 = engine.run_sweep(programs2, mode="map", **kw2)
+    _assert_same(ref2, out2, "auto-uniform")
+
+
+def test_pad_stats_waste_report():
+    """Every run_sweep result carries the padding-waste report; the
+    fractions must reflect the actual thread/program padding."""
+    cells = [("ticket", 2, 10_000), ("twa", 6, 10_000)]
+    programs, kw = pack_engine_cells(cells, ncs_max=100, seeds=3)
+    out = engine.run_sweep(programs, mode="map", **kw)
+    ps = out["pad_stats"]
+    assert ps["sum_events"] == int(out["events"].sum())
+    assert ps["max_events"] == int(out["events"].max())
+    n_threads = kw["init_pc"].shape[1]
+    expect_threads = np.asarray(kw["n_active"]).sum() / (2 * n_threads)
+    assert ps["live_thread_frac"] == pytest.approx(expect_threads)
+    assert 0 < ps["live_prog_frac"] < 1  # programs are padded to PROG_LEN
+    assert 0 < ps["live_mem_frac"] <= 1
+
+
+def _sweep_at_engine(mode, **geometry):
+    programs, kw = pack_engine_cells([("ticket", 2, 1_000)], ncs_max=100,
+                                     seeds=1)
+    return engine.run_sweep(programs, mode=mode, **geometry, **kw)
+
+
+def _sweep_at_workloads(mode, **geometry):
+    spec = SweepSpec(locks=("ticket",), threads=(2,), seeds=1, horizon=1_000)
+    return run_sweep(spec, mode=mode, **geometry)
+
+
+@pytest.mark.parametrize("entry", [_sweep_at_engine, _sweep_at_workloads],
+                         ids=["engine", "workloads"])
+def test_unknown_driver_is_refused(entry):
+    """A driver name outside ``engine.DRIVERS`` is the caller's error: a
+    ValueError that names the drivers there are."""
+    with pytest.raises(ValueError, match="'map', 'vmap', 'sched'"):
+        entry("pallas")
+
+
+@pytest.mark.parametrize("mode,knob", [(mode, knob)
+                                       for mode in ("map", "vmap")
+                                       for knob in ("lanes", "chunk")])
+def test_geometry_is_sched_only(mode, knob):
+    """``lanes`` and ``chunk`` shape the sched driver alone; given to
+    another driver they are refused, not ignored."""
+    with pytest.raises(ValueError, match="only apply to mode='sched'"):
+        _sweep_at_engine(mode, **{knob: 4})

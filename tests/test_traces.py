@@ -141,7 +141,7 @@ def test_schema_v2_fills_workload_for_v1_rows(trace_store):
 # ---------------------------------------------------------------------------
 
 def test_trace_scenarios_are_clean_across_all_modes():
-    """Oracle vs map/vmap/sched/pallas on trace-compiled scenarios — the
+    """Oracle vs map/vmap/sched on trace-compiled scenarios — the
     table-draw programs are under the same bit-identity contract as every
     other generated workload."""
     from repro.sim.check import fuzz
